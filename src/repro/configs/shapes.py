@@ -63,7 +63,11 @@ def _stub_inputs(cfg: ModelConfig, batch: int) -> Dict[str, jax.ShapeDtypeStruct
 
 def train_input_specs(cfg: ModelConfig, shape: str) -> Dict:
     sp = SHAPES[shape]
-    b, s = sp.global_batch, sp.seq_len
+    return train_batch_specs(cfg, sp.global_batch, sp.seq_len)
+
+
+def train_batch_specs(cfg: ModelConfig, b: int, s: int) -> Dict:
+    """Stand-ins for a (b, s) training batch (what ``SyntheticLM`` emits)."""
     specs = {"tokens": jax.ShapeDtypeStruct((b, s), jnp.int32),
              "labels": jax.ShapeDtypeStruct((b, s), jnp.int32)}
     specs.update(_stub_inputs(cfg, b))

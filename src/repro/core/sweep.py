@@ -82,6 +82,20 @@ def _pool_context():
         return multiprocessing.get_context()
 
 
+def _cpu_only_worker(initializer: Optional[Callable] = None,
+                     *initargs) -> None:
+    """Pool-worker start-up: keep the worker's JAX on the CPU.
+
+    A chip belongs to one process, and the parent may hold it; a DES task
+    that reaches for a JAX backend then gets the CPU instead of failing or
+    hanging on the chip."""
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    if "jax" in sys.modules:  # imported, but no backend can be up yet
+        sys.modules["jax"].config.update("jax_platforms", "cpu")
+    if initializer is not None:
+        initializer(*initargs)
+
+
 def parallel_map(fn: Callable, items: Sequence,
                  max_workers: Optional[int] = None,
                  parallel: bool = True,
@@ -101,8 +115,8 @@ def parallel_map(fn: Callable, items: Sequence,
         return [fn(x) for x in items]
     with ProcessPoolExecutor(max_workers=min(n, len(items)),
                              mp_context=_pool_context(),
-                             initializer=initializer,
-                             initargs=initargs) as pool:
+                             initializer=_cpu_only_worker,
+                             initargs=(initializer, *initargs)) as pool:
         return list(pool.map(fn, items))
 
 
@@ -329,11 +343,11 @@ class SimulationPool:
                 _set_worker_templates(self.templates)
             return [simulate_task(t) for t in tasks]
         if self._executor is None:
-            init = None if self.templates is None else _set_worker_templates
-            initargs = () if self.templates is None else (self.templates,)
+            initargs = (() if self.templates is None else
+                        (_set_worker_templates, self.templates))
             self._executor = ProcessPoolExecutor(
                 max_workers=self.max_workers, mp_context=_pool_context(),
-                initializer=init, initargs=initargs)
+                initializer=_cpu_only_worker, initargs=initargs)
         return list(self._executor.map(simulate_task, tasks))
 
     def close(self) -> None:

@@ -9,6 +9,11 @@ one q tile is carried across kv grid steps in VMEM scratch and flushed to
 the output block on the last kv step.  GQA is handled in the BlockSpec
 index maps (kv head = q head // group) — no materialized head broadcast.
 
+Layout: (B, S, H, D) is viewed as (B, S, H*D) (a free reshape), and a
+block is one head's (seq tile, head_dim) slab at lane offset head * D.  So
+the last two block dims, the ones Mosaic tiles, are (128, head_dim): a
+head_dim that is a multiple of 128 compiles for the TPU.
+
 MXU alignment: q/kv tiles default to 128 x head_dim with fp32 accumulation.
 Fully-masked (q, kv) tiles are skipped with ``pl.when`` (the causal upper
 triangle costs no FLOPs beyond the guard).
@@ -56,9 +61,9 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 
     @pl.when(run)
     def _compute():
-        q = q_ref[0, :, 0, :].astype(jnp.float32)   # (bq, d)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)   # (bk, d)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
+        q = q_ref[...].astype(jnp.float32)   # (bq, d)
+        k = k_ref[...].astype(jnp.float32)   # (bk, d)
+        v = v_ref[...].astype(jnp.float32)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         if causal:
@@ -66,12 +71,12 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
             if window > 0:
                 mask &= k_pos > q_pos - window
             s = jnp.where(mask, s, NEG_INF)
-        m_prev = m_scr[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-        p = jnp.exp(s - m_new[:, None])
+        m_prev = m_scr[...]                                   # (bq, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
         corr = jnp.exp(m_prev - m_new)
-        l_scr[...] = l_scr[...] * corr + jnp.sum(p, axis=1)
-        acc_scr[...] = acc_scr[...] * corr[:, None] + jax.lax.dot_general(
+        l_scr[...] = l_scr[...] * corr + jnp.sum(p, axis=1, keepdims=True)
+        acc_scr[...] = acc_scr[...] * corr + jax.lax.dot_general(
             p, v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         m_scr[...] = m_new
@@ -79,13 +84,13 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
     @pl.when(ik == nk - 1)
     def _flush():
         l = jnp.maximum(l_scr[...], 1e-30)
-        o_ref[0, :, 0, :] = (acc_scr[...] / l[:, None]).astype(o_ref.dtype)
+        o_ref[...] = (acc_scr[...] / l).astype(o_ref.dtype)
 
 
 def flash_attention_fwd(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                         causal: bool = True, window: int = 0,
                         block_q: int = 128, block_k: int = 128,
-                        interpret: bool = True) -> jnp.ndarray:
+                        interpret: bool = False) -> jnp.ndarray:
     """q: (B, S, H, D); k, v: (B, T, Kv, D) with H % Kv == 0."""
     b, s, h, d = q.shape
     t, kv = k.shape[1], k.shape[2]
@@ -101,23 +106,20 @@ def flash_attention_fwd(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         _attn_kernel, scale=scale, bq=bq, bk=bk, causal=causal,
         window=window, seq_q=s, seq_k=t)
 
-    return pl.pallas_call(
+    q_spec = pl.BlockSpec((None, bq, d), lambda ib, ih, iq, ik: (ib, iq, ih))
+    kv_spec = pl.BlockSpec((None, bk, d),
+                           lambda ib, ih, iq, ik: (ib, ik, ih // g))
+    out = pl.pallas_call(
         kernel,
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, bq, 1, d), lambda ib, ih, iq, ik: (ib, iq, ih, 0)),
-            pl.BlockSpec((1, bk, 1, d),
-                         lambda ib, ih, iq, ik, g=g: (ib, ik, ih // g, 0)),
-            pl.BlockSpec((1, bk, 1, d),
-                         lambda ib, ih, iq, ik, g=g: (ib, ik, ih // g, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, bq, 1, d),
-                               lambda ib, ih, iq, ik: (ib, iq, ih, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, s, h, d), q.dtype),
+        in_specs=[q_spec, kv_spec, kv_spec],
+        out_specs=q_spec,
+        out_shape=jax.ShapeDtypeStruct((b, s, h * d), q.dtype),
         scratch_shapes=[
-            pltpu.VMEM((bq,), jnp.float32),
-            pltpu.VMEM((bq,), jnp.float32),
+            pltpu.VMEM((bq, 1), jnp.float32),
+            pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, d), jnp.float32),
         ],
         interpret=interpret,
-    )(q, k, v)
+    )(q.reshape(b, s, h * d), k.reshape(b, t, kv * d), v.reshape(b, t, kv * d))
+    return out.reshape(b, s, h, d)

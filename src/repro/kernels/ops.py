@@ -1,18 +1,16 @@
 """jit'd public wrappers around the Pallas kernels.
 
-On this CPU container the kernels always run in ``interpret=True`` mode
-(the kernel body executes in Python for correctness validation); on a real
-TPU runtime set ``REPRO_PALLAS_INTERPRET=0`` to compile with Mosaic.
+The kernels are compiled with Mosaic when the program is lowered for a TPU
+and run in Pallas interpret mode when it is lowered for the CPU (the test
+path).  The choice is made per lowering platform (``_on_platform``), so no
+TPU program ever carries an interpreted kernel.
 
 Both ops carry custom VJPs that fall back to the jnp reference for the
-backward pass (the paper's contribution is systems-level; fused backward
-kernels are an optimization noted in EXPERIMENTS.md, not required for
-correctness).
+backward pass; fused backward kernels are not written yet.
 """
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -22,8 +20,12 @@ from .flash_attention import flash_attention_fwd
 from .rglru_scan import rglru_scan_fwd
 
 
-def _interpret() -> bool:
-    return os.environ.get("REPRO_PALLAS_INTERPRET", "1") != "0"
+def _on_platform(kernel_fwd, *args, **kw):
+    """Interpret on the CPU, compile everywhere else."""
+    return jax.lax.platform_dependent(
+        *args,
+        cpu=lambda *a: kernel_fwd(*a, interpret=True, **kw),
+        default=lambda *a: kernel_fwd(*a, interpret=False, **kw))
 
 
 # ---------------------------------------------------------------------------
@@ -33,8 +35,8 @@ def _interpret() -> bool:
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
 def flash_attention(q, k, v, causal: bool = True, window: int = 0):
-    return flash_attention_fwd(q, k, v, causal=causal, window=window,
-                               interpret=_interpret())
+    return _on_platform(flash_attention_fwd, q, k, v, causal=causal,
+                        window=window)
 
 
 def _fa_fwd(q, k, v, causal, window):
@@ -63,7 +65,7 @@ def rglru_scan(a, b):
     shape = a.shape
     a2 = a.reshape((-1,) + shape[-2:])
     b2 = b.reshape((-1,) + shape[-2:])
-    h = rglru_scan_fwd(a2, b2, interpret=_interpret())
+    h = _on_platform(rglru_scan_fwd, a2, b2)
     return h.reshape(shape)
 
 

@@ -76,11 +76,9 @@ def dryrun_cell(arch: str, shape: str, multi_pod: bool = False,
     try:
         if sp.kind == "train":
             opt = make_optimizer(get_optimizer_name(arch), lr=1e-3)
-            step = S.make_train_step(cfg, opt, mesh, rules)
             in_shardings, pshapes, oshapes = S.train_in_shardings(
                 cfg, opt, specs, mesh, rules)
-            jitted = jax.jit(step, in_shardings=in_shardings,
-                             donate_argnums=(0, 1))
+            jitted = S.jit_train_step(cfg, opt, in_shardings, mesh, rules)
             lowered = jitted.lower(pshapes, oshapes, specs)
             tokens = sp.global_batch * sp.seq_len
             model_flops = ha.model_flops_train(cfg, tokens)

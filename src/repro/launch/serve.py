@@ -15,6 +15,7 @@ import numpy as np
 
 from repro.configs import ARCH_IDS, get_config
 from repro.data import SyntheticLM
+from repro.launch.cache import use_compile_cache
 from repro.models import (init_decode_state, init_params,
                           precompute_cross_kv, serve_step)
 from repro.models.transformer import _get_encoder_states
@@ -29,6 +30,7 @@ def main() -> None:
     ap.add_argument("--gen", type=int, default=32)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = get_config(args.arch, smoke=args.smoke)
     key = jax.random.PRNGKey(args.seed)

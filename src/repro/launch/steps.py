@@ -112,6 +112,18 @@ def train_in_shardings(cfg: ModelConfig, optimizer: Optimizer,
             batch_shardings(batch_specs, mesh, rules)), pshapes, oshapes
 
 
+def jit_train_step(cfg: ModelConfig, optimizer: Optimizer, in_shardings,
+                   mesh: Mesh, rules: Optional[ShardingRules] = None):
+    """The train step jitted on ``mesh``: params and optimizer state keep
+    their ``train_in_shardings`` layout across steps (and are donated);
+    the metrics come back replicated."""
+    p_sh, o_sh, _ = in_shardings
+    return jax.jit(make_train_step(cfg, optimizer, mesh, rules),
+                   in_shardings=in_shardings,
+                   out_shardings=(p_sh, o_sh, NamedSharding(mesh, P())),
+                   donate_argnums=(0, 1))
+
+
 def serve_in_shardings(cfg: ModelConfig, state_shapes, token_batch: int,
                        mesh: Mesh,
                        rules: Optional[ShardingRules] = None):

@@ -182,6 +182,17 @@ class TestDataPipeline:
         np.testing.assert_array_equal(np.asarray(got["tokens"]),
                                       np.asarray(want["tokens"]))
 
+    def test_tokens_follow_zipf_unigram(self):
+        """Free (even) positions are Zipf draws: rank r has p ~ 1/r."""
+        cfg = get_config("gemma-7b", smoke=True)
+        toks = np.asarray(SyntheticLM(cfg, 16, 2047, seed=0)
+                          .next_batch()["tokens"])[:, ::2]
+        p = 1.0 / np.arange(1, cfg.vocab + 1)
+        p /= p.sum()
+        freq = np.bincount(toks.ravel(), minlength=cfg.vocab) / toks.size
+        # 16384 draws: binomial std of the top frequency is ~3e-3
+        np.testing.assert_allclose(freq[:4], p[:4], atol=0.015)
+
     def test_labels_shifted(self):
         cfg = get_config("gemma-7b", smoke=True)
         d = SyntheticLM(cfg, 2, 16, seed=0)

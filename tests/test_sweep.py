@@ -1,4 +1,6 @@
 """Parallel sweep engine: determinism (serial == parallel) and wiring."""
+import os
+
 from repro.core import sweep
 from repro.core.events import Op, StepTemplate, ps_resources
 from repro.core.simulator import SimConfig
@@ -29,6 +31,14 @@ def test_parallel_map_identical_to_serial():
 
 def test_parallel_map_preserves_order():
     assert sweep.parallel_map(abs, [-3, -1, -2]) == [3, 1, 2]
+
+
+def test_pool_workers_keep_jax_on_cpu(monkeypatch):
+    """A worker never reaches for the chip its parent may hold."""
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+    monkeypatch.delenv("REPRO_SWEEP_SERIAL", raising=False)
+    assert sweep.parallel_map(os.getenv, ["JAX_PLATFORMS"] * 2,
+                              max_workers=2) == ["cpu", "cpu"]
 
 
 def test_simulation_pool_reuses_executor():
