@@ -16,3 +16,7 @@ def use_compile_cache() -> None:
     ``JAX_COMPILATION_CACHE_DIR`` is set: JAX then reads it itself."""
     if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
         jax.config.update("jax_compilation_cache_dir", str(CACHE_DIR))
+    # Key on the HLO metadata too.  Without it a program that differs only
+    # in its op_name scopes (models/layers.scoped) reads an executable
+    # compiled without them, and the device trace loses its layer names.
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)

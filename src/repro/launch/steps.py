@@ -27,7 +27,9 @@ def make_train_step(cfg: ModelConfig, optimizer: Optimizer,
         with use_mesh(mesh, rules):
             (loss, metrics), grads = jax.value_and_grad(
                 transformer.loss_fn, has_aux=True)(params, batch, cfg)
-            new_params, new_opt = optimizer.update(grads, opt_state, params)
+            with jax.named_scope("optimizer"):
+                new_params, new_opt = optimizer.update(grads, opt_state,
+                                                       params)
         metrics = dict(metrics)
         metrics["loss"] = loss
         return new_params, new_opt, metrics

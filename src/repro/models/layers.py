@@ -7,6 +7,7 @@ production mesh.
 """
 from __future__ import annotations
 
+import functools
 import math
 from typing import Dict, Optional
 
@@ -17,6 +18,25 @@ from repro.parallel.sharding import role_size, shard
 from .config import ModelConfig
 
 Params = Dict[str, jnp.ndarray]
+
+
+def scoped(name: str):
+    """Run the decorated function under ``jax.named_scope(name)``.
+
+    Each layer kind has one such name (embed, norm, attention, mlp, moe,
+    recurrent, lm_head, optimizer).  It reaches the HLO ``op_name`` of the
+    layer's operations, through ``jvp``/``transpose`` and rematerialisation,
+    so a device trace can put time down to the layer; the compiled
+    instructions do not change.  ``jax.named_scope`` is looked up at each
+    call, so a test can compile the same step without scopes.
+    """
+    def wrap(fn):
+        @functools.wraps(fn)
+        def run(*args, **kwargs):
+            with jax.named_scope(name):
+                return fn(*args, **kwargs)
+        return run
+    return wrap
 
 
 # ---------------------------------------------------------------------------
@@ -47,6 +67,7 @@ def init_norm(cfg: ModelConfig, dim: Optional[int] = None) -> Params:
     return p
 
 
+@scoped("norm")
 def apply_norm(p: Params, x: jnp.ndarray, cfg: ModelConfig) -> jnp.ndarray:
     xf = x.astype(jnp.float32)
     if cfg.norm == "layernorm":
@@ -99,6 +120,7 @@ def init_mlp(key, cfg: ModelConfig, d_ff: Optional[int] = None) -> Params:
             "wo": dense_init(ks[2], (f, d))}
 
 
+@scoped("mlp")
 def apply_mlp(p: Params, x: jnp.ndarray, cfg: ModelConfig) -> jnp.ndarray:
     dt = x.dtype
     h = jnp.einsum("...d,df->...f", x, p["wi"].astype(dt))
@@ -246,6 +268,7 @@ def causal_mask(s: int, t: int, window: int = 0,
     return m[None, None]
 
 
+@scoped("attention")
 def attention_block(p: Params, x: jnp.ndarray, cfg: ModelConfig,
                     positions: jnp.ndarray, window: int = 0,
                     use_rope: bool = True,
@@ -270,6 +293,7 @@ def attention_block(p: Params, x: jnp.ndarray, cfg: ModelConfig,
     return jnp.einsum("...shk,hkd->...sd", out, p["wo"].astype(x.dtype))
 
 
+@scoped("attention")
 def cross_attention_block(p: Params, x: jnp.ndarray, enc: jnp.ndarray,
                           cfg: ModelConfig, gated: bool = True) -> jnp.ndarray:
     """Cross-attention: queries from x (B,S,d), keys/values from enc (B,T,d)."""
@@ -300,6 +324,7 @@ def init_kv_cache(cfg: ModelConfig, batch: int, max_len: int,
     return {"k": jnp.zeros(shape, dt), "v": jnp.zeros(shape, dt)}
 
 
+@scoped("attention")
 def decode_attention(p: Params, x: jnp.ndarray, cache_k: jnp.ndarray,
                      cache_v: jnp.ndarray, pos: jnp.ndarray,
                      cfg: ModelConfig, window: int = 0,

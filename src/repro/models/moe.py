@@ -22,7 +22,7 @@ import jax.numpy as jnp
 
 from repro.parallel.sharding import shard
 from .config import ModelConfig
-from .layers import Params, apply_mlp, dense_init, init_mlp
+from .layers import Params, apply_mlp, dense_init, init_mlp, scoped
 
 
 def init_moe(key, cfg: ModelConfig) -> Params:
@@ -49,6 +49,7 @@ def capacity(cfg: ModelConfig, group: int) -> int:
     return max(c, 1)
 
 
+@scoped("moe")
 def apply_moe(p: Params, x: jnp.ndarray,
               cfg: ModelConfig) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
     """x: (B, S, d) -> (out, aux_losses).

@@ -23,7 +23,7 @@ import jax.numpy as jnp
 
 from repro.parallel.sharding import shard
 from .config import ModelConfig
-from .layers import Params, dense_init
+from .layers import Params, dense_init, scoped
 
 _RGLRU_C = 8.0
 
@@ -79,6 +79,7 @@ def _causal_conv(x: jnp.ndarray, w: jnp.ndarray,
     return out
 
 
+@scoped("recurrent")
 def apply_rglru(p: Params, x: jnp.ndarray, cfg: ModelConfig) -> jnp.ndarray:
     """x: (B, S, d) -> (B, S, d). Zero initial state."""
     dt = x.dtype
@@ -183,6 +184,7 @@ def _chunked_time_scan(scan_fn, carry0, xs_t, seq_len: int,
     return carry, ys
 
 
+@scoped("recurrent")
 def apply_slstm(p: Params, x: jnp.ndarray, cfg: ModelConfig) -> jnp.ndarray:
     b, s, d = x.shape
     nh = cfg.n_heads
@@ -258,6 +260,7 @@ def _mlstm_gates(p: Params, x: jnp.ndarray):
     return q, k, v, i_t, f_t, og
 
 
+@scoped("recurrent")
 def apply_mlstm(p: Params, x: jnp.ndarray, cfg: ModelConfig) -> jnp.ndarray:
     b, s, d = x.shape
     nh = cfg.n_heads

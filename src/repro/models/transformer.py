@@ -228,8 +228,9 @@ def forward(params: Params, batch: Batch,
     tokens = batch["tokens"]
     b, s = tokens.shape
     dt = jnp.dtype(cfg.dtype)
-    x = jnp.take(params["embed"], tokens, axis=0).astype(dt)
-    x = x * math.sqrt(cfg.d_model)
+    with jax.named_scope("embed"):
+        x = jnp.take(params["embed"], tokens, axis=0).astype(dt)
+        x = x * math.sqrt(cfg.d_model)
     if cfg.encoder_layers:
         x = x + params["pos_embed"][None, :s].astype(dt)
     x = shard(x, "act_btd")
@@ -261,13 +262,14 @@ def forward(params: Params, batch: Batch,
         aux_total = jax.tree_util.tree_map(jnp.add, aux_total, a)
 
     x = apply_norm(params["final_norm"], x, cfg)
-    head = (params["embed"].T if cfg.tie_embeddings
-            else params["lm_head"])
-    logits = jnp.einsum("bsd,dv->bsv", x, head.astype(dt))
-    if cfg.logits_softcap > 0:
-        logits = cfg.logits_softcap * jnp.tanh(
-            logits.astype(jnp.float32) / cfg.logits_softcap).astype(dt)
-    logits = _mask_pad_vocab(logits, cfg)
+    with jax.named_scope("lm_head"):
+        head = (params["embed"].T if cfg.tie_embeddings
+                else params["lm_head"])
+        logits = jnp.einsum("bsd,dv->bsv", x, head.astype(dt))
+        if cfg.logits_softcap > 0:
+            logits = cfg.logits_softcap * jnp.tanh(
+                logits.astype(jnp.float32) / cfg.logits_softcap).astype(dt)
+        logits = _mask_pad_vocab(logits, cfg)
     logits = shard(logits, "logits")
     return logits, aux_total
 
@@ -284,13 +286,14 @@ def loss_fn(params: Params, batch: Batch,
             cfg: ModelConfig) -> Tuple[jnp.ndarray, Dict]:
     logits, aux = forward(params, batch, cfg)
     labels = batch["labels"]
-    logits = logits.astype(jnp.float32)
-    logz = jax.scipy.special.logsumexp(logits, axis=-1)
-    label_logit = jnp.take_along_axis(logits, labels[..., None],
-                                      axis=-1)[..., 0]
-    mask = batch.get("mask", jnp.ones_like(labels, jnp.float32))
-    ce = jnp.sum((logz - label_logit) * mask) / jnp.maximum(
-        jnp.sum(mask), 1.0)
+    with jax.named_scope("lm_head"):
+        logits = logits.astype(jnp.float32)
+        logz = jax.scipy.special.logsumexp(logits, axis=-1)
+        label_logit = jnp.take_along_axis(logits, labels[..., None],
+                                          axis=-1)[..., 0]
+        mask = batch.get("mask", jnp.ones_like(labels, jnp.float32))
+        ce = jnp.sum((logz - label_logit) * mask) / jnp.maximum(
+            jnp.sum(mask), 1.0)
     loss = ce + aux["aux_loss"] + aux["z_loss"]
     return loss, {"ce": ce, **aux}
 
