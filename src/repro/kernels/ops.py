@@ -3,10 +3,12 @@
 The kernels are compiled with Mosaic when the program is lowered for a TPU
 and run in Pallas interpret mode when it is lowered for the CPU (the test
 path).  The choice is made per lowering platform (``_on_platform``), so no
-TPU program ever carries an interpreted kernel.
+TPU program ever carries an interpreted kernel; a caller that has made that
+choice itself passes ``interpret=False``.
 
-Both ops carry custom VJPs that fall back to the jnp reference for the
-backward pass; fused backward kernels are not written yet.
+``flash_attention``'s custom VJP runs the Pallas backward kernels (dQ and
+dK/dV) on the forward's output and logsumexp.  ``rglru_scan``'s backward is
+an associative scan of the adjoint recurrence.
 """
 from __future__ import annotations
 
@@ -15,8 +17,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from . import ref
-from .flash_attention import flash_attention_fwd
+from .flash_attention import flash_attention_bwd, flash_attention_fwd
 from .rglru_scan import rglru_scan_fwd
 
 
@@ -33,25 +34,36 @@ def _on_platform(kernel_fwd, *args, **kw):
 # ---------------------------------------------------------------------------
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def flash_attention(q, k, v, causal: bool = True, window: int = 0):
-    return _on_platform(flash_attention_fwd, q, k, v, causal=causal,
-                        window=window)
+def flash_attention(q, k, v, causal: bool = True, window: int = 0,
+                    interpret=None):
+    """Fused attention with the Pallas kernels forward and backward.
+    ``interpret`` None: Mosaic on a TPU lowering, interpreted on the CPU;
+    False: Mosaic, for a caller that has already chosen the TPU (each
+    kernel is then traced once, not once a platform)."""
+    if interpret is None:
+        return _on_platform(
+            lambda q, k, v, interpret: _flash(q, k, v, causal, window,
+                                              interpret), q, k, v)
+    return _flash(q, k, v, causal, window, interpret)
 
 
-def _fa_fwd(q, k, v, causal, window):
-    return flash_attention(q, k, v, causal, window), (q, k, v)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _flash(q, k, v, causal, window, interpret):
+    return _fa_fwd(q, k, v, causal, window, interpret)[0]
 
 
-def _fa_bwd(causal, window, res, g):
-    q, k, v = res
-    _, vjp = jax.vjp(
-        lambda q, k, v: ref.flash_attention_ref(q, k, v, causal=causal,
-                                                window=window), q, k, v)
-    return vjp(g)
+def _fa_fwd(q, k, v, causal, window, interpret):
+    o, lse = flash_attention_fwd(q, k, v, causal=causal, window=window,
+                                 interpret=interpret)
+    return o, (q, k, v, o, lse)
 
 
-flash_attention.defvjp(_fa_fwd, _fa_bwd)
+def _fa_bwd(causal, window, interpret, res, do):
+    return flash_attention_bwd(*res, do, causal=causal, window=window,
+                               interpret=interpret)
+
+
+_flash.defvjp(_fa_fwd, _fa_bwd)
 
 
 # ---------------------------------------------------------------------------

@@ -74,17 +74,22 @@ class ModelConfig:
     logits_softcap: float = 0.0
     dtype: str = "bfloat16"
     remat: bool = True          # rematerialize each scan group
-    use_flash_kernel: bool = False  # Pallas flash-attention path (TPU target)
+    use_flash_kernel: bool = False  # Pallas RG-LRU scan (TPU target)
     attention_impl: str = "naive"   # naive | chunked (online-softmax over
     #                                 kv blocks; flash semantics in pure JAX
-    #                                 — the dry-run-measurable hillclimb)
+    #                                 — the dry-run-measurable hillclimb);
+    #                                 on a TPU the fused Pallas kernel
+    #                                 comes first where it fits
+    #                                 (layers.fused_attention_fits)
     attention_chunk: int = 1024     # kv block for attention_impl="chunked"
     time_chunk: int = 0             # recurrent blocks: remat the time scan
     #                                 in chunks of this many steps (memory
     #                                 hillclimb for sLSTM/mLSTM)
     scores_dtype: str = "float32"   # attention score/prob dtype: float32
     #                                 (exact baseline) | bfloat16 (halves
-    #                                 score-chain HBM traffic; §Perf)
+    #                                 score-chain HBM traffic; §Perf); the
+    #                                 naive chain only: the fused kernel
+    #                                 keeps its scores in f32
     seq_parallel_residual: bool = False  # shard the residual stream on the
     #                                 sequence dim between blocks (TP all-
     #                                 reduce -> reduce-scatter + all-gather;

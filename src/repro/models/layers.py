@@ -14,7 +14,7 @@ from typing import Dict, Optional
 import jax
 import jax.numpy as jnp
 
-from repro.parallel.sharding import role_size, shard
+from repro.parallel.sharding import current_mesh, role_size, shard
 from .config import ModelConfig
 
 Params = Dict[str, jnp.ndarray]
@@ -268,6 +268,35 @@ def causal_mask(s: int, t: int, window: int = 0,
     return m[None, None]
 
 
+# Shortest sequence at which the fused kernel beats the naive score chain
+# on a TPU v5e, forward, remat recompute and backward (chip sweep over the
+# zoo's head layouts, PERF.md section 5): from 512 where a kv head serves a
+# group of query heads; from 1024 where it serves one (a grid step then
+# holds one head's work for its K and V tiles).
+FUSED_ATTENTION_MIN_SEQ = 512
+FUSED_ATTENTION_MIN_SEQ_MHA = 1024
+
+
+def fused_attention_fits(q: jnp.ndarray, k: jnp.ndarray, causal: bool,
+                         window: int) -> bool:
+    """Self-attention over q: (B, S, H, D), k: (B, S, Kv, D) takes the
+    fused Pallas kernel (``kernels.ops.flash_attention``) on a TPU lowering:
+    causal with no window, S at least the threshold above for its group,
+    operands held whole by one device (the kernel has no partitioning
+    rule), and a shape the kernel's tiles fit
+    (``kernels.flash_attention.fits``)."""
+    s, group = q.shape[1], q.shape[2] // k.shape[2]
+    min_seq = (FUSED_ATTENTION_MIN_SEQ_MHA if group == 1
+               else FUSED_ATTENTION_MIN_SEQ)
+    mc = current_mesh()
+    if not (causal and window == 0 and s >= min_seq
+            and (mc is None or mc.mesh.size == 1)):
+        return False
+    # imported only here: Pallas adds a second to the start of a process
+    from repro.kernels.flash_attention import fits
+    return fits(s, s, group, q.shape[3])
+
+
 @scoped("attention")
 def attention_block(p: Params, x: jnp.ndarray, cfg: ModelConfig,
                     positions: jnp.ndarray, window: int = 0,
@@ -279,16 +308,23 @@ def attention_block(p: Params, x: jnp.ndarray, cfg: ModelConfig,
         q = apply_rope(q, positions, cfg.rope_theta)
         k = apply_rope(k, positions, cfg.rope_theta)
     q, k, v = _shard_q(q), _shard_kv(k), _shard_kv(v)
-    if cfg.use_flash_kernel and causal and x.shape[1] >= 256 and window == 0:
-        from repro.kernels.ops import flash_attention
-        out = flash_attention(q, k, v, causal=True)
-    elif (cfg.attention_impl == "chunked" and causal
-          and x.shape[1] > cfg.attention_chunk):
-        out = chunked_attention(q, k, v, cfg, causal=True, window=window)
-    else:
+
+    def unfused(q, k, v):
+        if (cfg.attention_impl == "chunked" and causal
+                and x.shape[1] > cfg.attention_chunk):
+            return chunked_attention(q, k, v, cfg, causal=True, window=window)
         mask = (causal_mask(x.shape[1], x.shape[1], window=window)
                 if causal else None)
-        out = mha_logits_to_out(q, k, v, mask, cfg)
+        return mha_logits_to_out(q, k, v, mask, cfg)
+
+    if fused_attention_fits(q, k, causal, window):
+        from repro.kernels.ops import flash_attention
+        out = jax.lax.platform_dependent(
+            q, k, v,
+            tpu=lambda q, k, v: flash_attention(q, k, v, interpret=False),
+            default=unfused)
+    else:
+        out = unfused(q, k, v)
     out = shard(out, "act_heads")
     return jnp.einsum("...shk,hkd->...sd", out, p["wo"].astype(x.dtype))
 
