@@ -3,9 +3,11 @@ reference, and the readings of every metric.
 
 Everything a cell is made of is found by name in files: its entry in
 ``BENCHMARK.json``, ``configs/<config>.json`` (the file the entry names),
-``traffic/<traffic>.json``, ``limits/<workload>.json`` and one reader
-``metrics/<metric>.py`` per metric.  Adding a cell or a metric adds files
-and entries and edits none.
+``traffic/<traffic>.json``, ``limits/<workload>.json``, one reader
+``metrics/<metric>.py`` per metric and one reference block
+``chipbench/blocks/<kind>.py`` per layer kind of the configuration's
+``model.pattern``.  Adding a cell, a metric or a layer kind adds files and
+entries and edits none.
 
 The window drives the program's train step as ``repro.launch.train.run``
 builds it (``make_mesh``, ``train_in_shardings``, ``jit_train_step``
@@ -24,26 +26,22 @@ import resource
 import shutil
 import sys
 import time
-from dataclasses import dataclass
+from dataclasses import dataclass, is_dataclass
 from pathlib import Path
-from typing import Callable, Dict, List, Optional, Sequence
+from typing import (Callable, Dict, List, Optional, Sequence, get_args,
+                    get_type_hints)
 
 import jax
 import numpy as np
 from jax.profiler import TraceAnnotation
 
-from chipbench import compare, flops, trace, traffic, weights
+from chipbench import blocks, compare, flops, trace, traffic, weights
 from chipbench.reference import Reference
 
 BENCH_DIR = Path(__file__).resolve().parents[1]
 ROOT = BENCH_DIR.parents[1]
 OUT_DIR = ROOT / ".bench_out"
 CHECKED_STEPS = 3
-# ModelConfig fields a configuration file sets; norm_eps is fixed in the
-# program (1e-6) and only the reference reads it.
-PROGRAM_KEYS = ("n_layers", "d_model", "n_heads", "n_kv", "head_dim", "d_ff",
-                "vocab", "mlp", "norm", "rope_theta", "tie_embeddings",
-                "dtype", "scores_dtype", "remat")
 
 
 @dataclass
@@ -111,10 +109,24 @@ class Context:
 
 
 def model_config(config: dict):
-    """The program's ``ModelConfig`` for a configuration file."""
+    """The program's ``ModelConfig`` for a configuration file: the registry's
+    entry for ``arch`` with every key of ``model`` that names a field of
+    ``ModelConfig`` (a dict becomes the field's dataclass, a list a tuple),
+    and the reference's ``pattern``.  Keys that name no field, such as
+    ``norm_eps`` (fixed at 1e-6 in the program), only the reference reads."""
     from repro.configs import get_config
-    return get_config(config["arch"]).replace(
-        **{k: config["model"][k] for k in PROGRAM_KEYS})
+    from repro.models.config import ModelConfig
+    hints = get_type_hints(ModelConfig)
+    kw = {"pattern": blocks.pattern(config["model"])}
+    for key, value in config["model"].items():
+        if key not in hints:
+            continue
+        if isinstance(value, dict):
+            cls = next(t for t in (hints[key], *get_args(hints[key]))
+                       if is_dataclass(t))
+            value = cls(**value)
+        kw[key] = tuple(value) if isinstance(value, list) else value
+    return get_config(config["arch"]).replace(**kw)
 
 
 class Program:
@@ -130,10 +142,8 @@ class Program:
         from repro.optim import make_optimizer
 
         m, hp = config["model"], config["optimizer"]
+        want = weights.flatten(weights.shapes(m))    # refuses unknown kinds
         self.cfg = model_config(config)
-        if self.cfg.pattern != ("attn",):
-            raise ValueError(f"pattern {self.cfg.pattern}: weights.py lays "
-                             "out one attn block a layer")
         self.opt = make_optimizer(
             hp["name"], lr=hp["lr"], b1=hp["b1"], b2=hp["b2"], eps=hp["eps"],
             weight_decay=hp["weight_decay"])
@@ -142,7 +152,6 @@ class Program:
         in_sh, pshapes, oshapes = train_in_shardings(self.cfg, self.opt,
                                                      specs, self.mesh)
         self.p_sh, self.o_sh, self.b_sh = in_sh
-        want = weights.flatten(weights.shapes(m))
         have = weights.flatten(param_shapes(self.cfg))
         if {p: x.shape for p, x in want.items()} != \
                 {p: x.shape for p, x in have.items()}:

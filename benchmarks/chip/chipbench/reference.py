@@ -1,10 +1,13 @@
-"""Plain float32 reference of the dense decoder's training step.
+"""Plain float32 reference of the decoder's training step.
 
 Forward pass, loss, gradients and AdamW, written from the published
-description of a pre-norm GQA decoder (RoPE, RMSNorm or LayerNorm, SwiGLU or
-tanh-GELU MLP, an LM head of its own or the embedding's transpose, mean
-token cross-entropy) in plain ``jax.numpy``.  It imports nothing of the
-program and takes nothing the program made: the weights come from
+description of a pre-norm decoder (RoPE, RMSNorm or LayerNorm, an LM head
+of its own or the embedding's transpose, mean token cross-entropy) in plain
+``jax.numpy``.  Each layer is the block of its kind in the configuration's
+``model.pattern``, from ``blocks/<kind>.py`` (``attn``: GQA attention and a
+SwiGLU or tanh-GELU MLP; ``local``: the same with a sliding window); a kind
+is added as one more file there, with no edit here.  It imports nothing of
+the program and takes nothing the program made: the weights come from
 ``weights.init`` and the seed, the rows are the tokens the timed path was
 fed.
 
@@ -28,7 +31,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from chipbench import weights
+from chipbench import blocks, weights
 from chipbench.compare import leaf_norms
 
 HIGHEST = jax.lax.Precision.HIGHEST
@@ -70,54 +73,28 @@ def _rope(x, theta):
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
 
 
-def _attention(p, x, m, low):
-    b, s, _ = x.shape
-    h, kv, hd = m["n_heads"], m["n_kv"], m["head_dim"]
-    g = h // kv
-    q = _rope(_mm("bsd,dhk->bshk", x, p["wq"], low), m["rope_theta"])
-    k = _rope(_mm("bsd,dhk->bshk", x, p["wk"], low), m["rope_theta"])
-    v = _mm("bsd,dhk->bshk", x, p["wv"], low)
-    qg = q.reshape(b, s, kv, g, hd)
-    qb = min(s, Q_BLOCK)
-
-    @jax.checkpoint
-    def block(i):
-        qi = jax.lax.dynamic_slice_in_dim(qg, i * qb, qb, axis=1)
-        sc = _mm("bqkgd,btkd->bkgqt", qi, k, low) / math.sqrt(hd)
-        causal = jnp.arange(s)[None, :] <= (i * qb + jnp.arange(qb))[:, None]
-        sc = jnp.where(causal, sc, -jnp.inf)
-        w = jax.nn.softmax(sc, axis=-1)
-        return _mm("bkgqt,btkd->bqkgd", w, v, low).reshape(b, qb, h, hd)
-
-    out = jax.lax.map(block, jnp.arange(s // qb))       # (n, B, qb, H, D)
-    out = jnp.moveaxis(out, 0, 1).reshape(b, s, h, hd)
-    return _mm("bshk,hkd->bsd", out, p["wo"], low)
-
-
-def _mlp(p, x, m, low):
-    hi = _mm("bsd,df->bsf", x, p["wi"], low)
-    if m["mlp"] == "swiglu":
-        hi = jax.nn.silu(_mm("bsd,df->bsf", x, p["wg"], low)) * hi
-    elif m["mlp"] == "gelu":
-        hi = jax.nn.gelu(hi, approximate=True)
-    else:
-        raise ValueError(f"mlp {m['mlp']!r} not in the reference")
-    return _mm("bsf,fd->bsd", hi, p["wo"], low)
+def _at(tree, path: str):
+    for k in path.split("/"):
+        tree = tree[k]
+    return tree
 
 
 def loss_sum(params, tokens, labels, m, low=None, rows_local=None):
-    """Sum over every token of the cross-entropy of its label."""
+    """Sum over every token of the cross-entropy of its label, plus the
+    layers' extra loss once for each token."""
     b, s = tokens.shape
     x = jnp.take(params["embed"], tokens, axis=0) * math.sqrt(m["d_model"])
-    blk = params["scan"]["s0_attn"]
-
-    @jax.checkpoint
-    def layer(x, lp):
-        x = x + _attention(lp["attn"], _norm(lp["norm1"], x, m), m, low)
-        return x + _mlp(lp["mlp"], _norm(lp["norm2"], x, m), m, low)
-
-    for i in range(m["n_layers"]):
-        x = layer(x, jax.tree_util.tree_map(lambda a, i=i: a[i], blk))
+    groups, scan, tail = blocks.slots(m)
+    layers = [(kind, jax.tree_util.tree_map(lambda a, g=g: a[g],
+                                            _at(params, path)))
+              for g in range(groups) for path, kind in scan]
+    layers += [(kind, _at(params, path)) for path, kind in tail]
+    extra = jnp.zeros((), jnp.float32)
+    for kind, lp in layers:
+        apply = blocks.load(kind).apply
+        x, e = jax.checkpoint(lambda lp, x, apply=apply: apply(lp, x, m, low))(
+            lp, x)
+        extra = extra + e
     x = _norm(params["final_norm"], x, m)
 
     tb = min(s, max(1, TOKENS_PER_HEAD_BLOCK // (rows_local or b)))
@@ -136,7 +113,7 @@ def loss_sum(params, tokens, labels, m, low=None, rows_local=None):
         lse = jax.scipy.special.logsumexp(z, axis=-1)
         return jnp.sum(lse - jnp.take_along_axis(z, yi[..., None], -1)[..., 0])
 
-    return jnp.sum(jax.lax.map(head, jnp.arange(s // tb)))
+    return jnp.sum(jax.lax.map(head, jnp.arange(s // tb))) + extra * (b * s)
 
 
 def adamw(p, g, mu, nu, t, hp):
@@ -151,7 +128,8 @@ def adamw(p, g, mu, nu, t, hp):
 
 def fsdp_shardings(tree, mesh: Mesh):
     """Each leaf split over every device along its largest dimension that
-    they divide (never the stacked-layer axis); the rest replicated."""
+    they divide (never the stacked-layer axis of a ``scan/`` leaf; a
+    ``tail/`` leaf is one layer); the rest replicated."""
     n = mesh.devices.size
 
     def leaf(path, x):

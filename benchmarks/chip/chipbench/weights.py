@@ -4,9 +4,12 @@ takes.
 The benchmark makes the weights itself, on the device, in one jitted call,
 so that the reference can make the very same ones from the seed without
 taking anything from the program.  The layout is the program's parameter
-pytree for a dense decoder whose layer pattern is one ``attn`` block: the
-layers stacked on a leading axis under ``scan/s0_attn``.  ``run.py`` checks
-it against the program's own ``param_shapes`` before any step runs.
+pytree: the embedding, the LM head unless it is tied, the final norm, and
+each layer's leaves as its kind's ``blocks/<kind>.py`` lays them out, under
+``scan/s{i}_{kind}`` stacked over the pattern's groups and ``tail/t{i}_{kind}``
+for the remainder.  A new layer kind adds its file there and edits nothing
+here.  ``harness.Program`` checks the layout against the program's own
+``param_shapes`` before any step runs.
 """
 from __future__ import annotations
 
@@ -14,6 +17,8 @@ import math
 
 import jax
 import jax.numpy as jnp
+
+from chipbench import blocks
 
 # The program pads the vocabulary to a multiple of this, so the LM head
 # shards over the tensor-parallel axis; logits past ``vocab`` are masked.
@@ -33,38 +38,27 @@ def padded_vocab(m: dict) -> int:
     return -(-m["vocab"] // VOCAB_PAD) * VOCAB_PAD
 
 
+def norm_layout(prefix: str, m: dict) -> dict:
+    """A norm's leaves: a scale, and a bias for LayerNorm."""
+    out = {f"{prefix}/scale": ((m["d_model"],), "ones")}
+    if m["norm"] == "layernorm":
+        out[f"{prefix}/bias"] = ((m["d_model"],), "zeros")
+    return out
+
+
 def layout(m: dict) -> dict:
     """{path: (shape, std or 'ones' / 'zeros')} for the model dict ``m`` of
     a configuration file."""
-    d, h, kv, hd, f = (m["d_model"], m["n_heads"], m["n_kv"], m["head_dim"],
-                       m["d_ff"])
-    L, V = m["n_layers"], padded_vocab(m)
-    ln = m["norm"] == "layernorm"
-
-    def norm(prefix, stack):
-        lead = (L,) if stack else ()
-        out = {f"{prefix}/scale": (lead + (d,), "ones")}
-        if ln:
-            out[f"{prefix}/bias"] = (lead + (d,), "zeros")
-        return out
-
-    blk = "scan/s0_attn"
+    d, V = m["d_model"], padded_vocab(m)
     out = {"embed": ((V, d), 0.02)}
     if not m["tie_embeddings"]:          # tied: the head is embed.T
         out["lm_head"] = ((d, V), 1 / math.sqrt(d))
-    out.update(norm("final_norm", False))
-    out.update(norm(f"{blk}/norm1", True))
-    out.update(norm(f"{blk}/norm2", True))
-    out.update({
-        f"{blk}/attn/wq": ((L, d, h, hd), 1 / math.sqrt(d)),
-        f"{blk}/attn/wk": ((L, d, kv, hd), 1 / math.sqrt(d)),
-        f"{blk}/attn/wv": ((L, d, kv, hd), 1 / math.sqrt(d)),
-        f"{blk}/attn/wo": ((L, h, hd, d), 1 / math.sqrt(h * hd)),
-        f"{blk}/mlp/wi": ((L, d, f), 1 / math.sqrt(d)),
-        f"{blk}/mlp/wo": ((L, f, d), 1 / math.sqrt(f)),
-    })
-    if m["mlp"] in ("swiglu", "geglu"):
-        out[f"{blk}/mlp/wg"] = ((L, d, f), 1 / math.sqrt(d))
+    out.update(norm_layout("final_norm", m))
+    groups, scan, tail = blocks.slots(m)
+    for slots, lead in ((scan, (groups,)), (tail, ())):
+        for prefix, kind in slots:
+            for path, (shape, std) in blocks.load(kind).layout(m).items():
+                out[f"{prefix}/{path}"] = (lead + tuple(shape), std)
     return out
 
 

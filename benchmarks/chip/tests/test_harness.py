@@ -6,11 +6,18 @@ import shutil
 import subprocess
 import sys
 
+import jax
 import pytest
 
+import tiny
 from chipbench import harness, traffic
 
 BENCH = json.loads((harness.ROOT / "BENCHMARK.json").read_text())
+# What a configuration file states, so that neither side takes it from
+# elsewhere: the program's registry or the reference's defaults.
+STATED = {"n_layers", "d_model", "n_heads", "n_kv", "head_dim", "d_ff",
+          "vocab", "mlp", "norm", "norm_eps", "rope_theta", "tie_embeddings",
+          "dtype", "scores_dtype", "remat"}
 
 
 def test_finds_config_traffic_limits_and_readers_by_name(tmp_path):
@@ -46,11 +53,25 @@ def test_finds_config_traffic_limits_and_readers_by_name(tmp_path):
         harness.load_cell("m-1l.mix_b", root=tmp_path, bench_dir=bench_dir)
 
 
+def _same(have, stated):
+    """A field of the program's config holds what the file states: a list
+    as a tuple, a dict as its dataclass."""
+    if isinstance(stated, list):
+        return have == tuple(stated)
+    if isinstance(stated, dict):
+        return all(_same(getattr(have, k), v) for k, v in stated.items())
+    return have == stated
+
+
 @pytest.mark.parametrize("w", [w["name"] for w in BENCH["workloads"]])
 def test_every_cell_has_its_files(w):
     cell = harness.load_cell(w)
     m = cell.config["model"]
-    assert set(harness.PROGRAM_KEYS) | {"norm_eps"} <= set(m)
+    assert STATED <= set(m)
+    cfg = harness.model_config(cell.config)
+    for key, value in m.items():
+        if hasattr(cfg, key):
+            assert _same(getattr(cfg, key), value), key
     assert cell.config["chips"] == cell.chips
     conf = next(c for c in BENCH["configs"]
                 if c["name"] == cell.config["name"])
@@ -65,6 +86,24 @@ def test_every_cell_has_its_files(w):
     assert "setup_s" in names and len(names) >= 2
     assert cell.per_layer
     assert {m["moves"] for m in cell.per_layer} <= names
+
+
+def test_a_block_kind_with_no_file_is_refused_by_name():
+    cell = tiny.cell(pattern=["rglru"])
+    with pytest.raises(ValueError, match="no block kind 'rglru'"):
+        harness.Program(cell.config, cell.mix, jax.devices()[:1])
+
+
+def test_a_stated_pattern_reaches_the_program():
+    model = dict(tiny.MODEL, pattern=["local", "attn"], window=16,
+                 moe={"num_experts": 8, "top_k": 2})
+    cfg = harness.model_config({"arch": "granite-8b", "model": model})
+    assert cfg.pattern == ("local", "attn") and cfg.window == 16
+    assert cfg.moe.num_experts == 8 and cfg.moe.top_k == 2
+    assert (cfg.d_model, cfg.dtype) == (64, "float32")
+    default = harness.model_config({"arch": "granite-8b",
+                                    "model": dict(tiny.MODEL)})
+    assert default.pattern == ("attn",) and default.moe is None
 
 
 def test_a_mix_asking_for_what_the_generator_lacks_is_refused():
