@@ -1,4 +1,4 @@
-"""Architecture registry: the 10 assigned configs (+ reduced smoke forms).
+"""Architecture registry: the 11 assigned configs (+ reduced smoke forms).
 
 Usage: ``get_config("gemma-7b")``, ``get_config("gemma-7b", smoke=True)``,
 ``--arch <id>`` in the launchers.
@@ -21,6 +21,7 @@ _ARCH_MODULES = {
     "xlstm-350m": "xlstm_350m",
     "recurrentgemma-2b": "recurrentgemma_2b",
     "llama-3.2-vision-90b": "llama_3_2_vision_90b",
+    "mellum2-12b": "mellum2_12b",
 }
 
 ARCH_IDS: List[str] = list(_ARCH_MODULES)
@@ -34,14 +35,8 @@ def _module(arch: str):
 
 # Beyond-paper optimized variants (§Perf hillclimbs; EXPERIMENTS.md).
 # Baseline configs stay paper-faithful/naive; these are opt-in.
-import dataclasses as _dc
-
 _OPTIMIZED_OVERRIDES = {
-    "deepseek-moe-16b": lambda c: c.replace(
-        moe=_dc.replace(c.moe, dispatch_local=True)),
-    "arctic-480b": lambda c: c.replace(
-        moe=_dc.replace(c.moe, dispatch_local=True),
-        scores_dtype="bfloat16"),
+    "arctic-480b": lambda c: c.replace(scores_dtype="bfloat16"),
     "granite-8b": lambda c: c.replace(
         scores_dtype="bfloat16", seq_parallel_residual=True),
     "phi4-mini-3.8b": lambda c: c.replace(

@@ -257,11 +257,12 @@ def model_flops_decode(cfg, tokens: int, kv_len: int) -> float:
     """2·N_active per token plus attention reads over the KV cache."""
     from repro.models.transformer import active_param_count
     base = 2.0 * active_param_count(cfg) * tokens
+    from repro.models.config import WINDOW_KINDS
     n_attn = sum(1 for k in (cfg.pattern * cfg.n_groups +
                              cfg.tail_pattern)
                  if k in ("attn", "moe", "encdec"))
     n_local = sum(1 for k in (cfg.pattern * cfg.n_groups +
-                              cfg.tail_pattern) if k == "local")
+                              cfg.tail_pattern) if k in WINDOW_KINDS)
     attn = 2.0 * 2.0 * cfg.n_heads * cfg.head_dim * (
         n_attn * kv_len + n_local * min(kv_len, cfg.window or kv_len))
     return base + attn * tokens

@@ -32,7 +32,7 @@ from repro.core.events import Op, ResourceSpec, StepTemplate, LINK, COMPUTE
 from repro.core.simulator import SimConfig, Simulation
 from repro.core.hlo_analysis import (DCN_BW, HBM_BW, ICI_BW, ICI_LINKS,
                                      PEAK_FLOPS)
-from repro.models.config import ModelConfig
+from repro.models.config import MOE_KINDS, ModelConfig
 
 
 @dataclass(frozen=True)
@@ -69,7 +69,7 @@ def _layer_param_bytes(cfg: ModelConfig) -> List[Tuple[str, float, float]]:
         kind = cfg.pattern[li % len(cfg.pattern)]
         attn = (d * cfg.n_heads * cfg.head_dim * 2
                 + d * cfg.n_kv * cfg.head_dim * 2)
-        if kind == "moe":
+        if kind in MOE_KINDS:
             m = cfg.moe
             fe = cfg.d_expert_eff
             routed = m.num_experts * 3 * d * fe

@@ -14,6 +14,7 @@ Block kinds:
   ``attn``   causal global self-attention + MLP
   ``local``  sliding-window self-attention + MLP
   ``moe``    causal self-attention + MoE FFN (optionally + dense residual FFN)
+  ``local_moe`` sliding-window self-attention + MoE FFN
   ``rglru``  RG-LRU recurrent mixing block + MLP
   ``slstm``  sLSTM block (scalar memory, exponential gating)
   ``mlstm``  mLSTM block (matrix memory, chunkwise-parallel)
@@ -25,8 +26,12 @@ import dataclasses
 from dataclasses import dataclass
 from typing import Optional, Tuple
 
-BLOCK_KINDS = ("attn", "local", "moe", "rglru", "slstm", "mlstm", "xattn",
+BLOCK_KINDS = ("attn", "local", "moe", "local_moe", "rglru", "slstm",
+               "mlstm", "xattn",
                "encdec")  # encdec = self-attn + cross-attn + MLP (Whisper)
+MOE_KINDS = ("moe", "local_moe")
+# Kinds whose self-attention sees a sliding window of ``window`` keys.
+WINDOW_KINDS = ("local", "local_moe")
 
 
 @dataclass(frozen=True)
@@ -35,14 +40,28 @@ class MoEConfig:
     top_k: int
     num_shared: int = 0         # always-on shared experts
     d_expert: int = 0           # per-expert hidden dim (0 -> d_ff)
-    capacity_factor: float = 1.25
     router_z_coef: float = 1e-3
     aux_coef: float = 1e-2
-    group_size: int = 1024      # dispatch group (tokens) for the MTF-style
-                                # einsum dispatch; bounds dispatch FLOPs
-    dispatch_local: bool = False  # keep the group dim data-sharded through
-                                  # dispatch/combine (a2a instead of token
-                                  # all-gather; §Perf hillclimb)
+    held: int = 0               # routed experts this layer holds, a
+    first_held: int = 0         # contiguous block from ``first_held``
+    #                             (expert parallelism: one chip's share);
+    #                             0 -> all of them
+
+    @property
+    def n_held(self) -> int:
+        return self.held or self.num_experts
+
+
+@dataclass(frozen=True)
+class YarnConfig:
+    """YaRN rotary scaling of the full-attention layers (Peng et al. 2023;
+    the HF rope utilities' ``_compute_yarn_parameters``)."""
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float = 1.0   # scales cos and sin: scores carry its
+    #                                 square
 
 
 @dataclass(frozen=True)
@@ -60,7 +79,8 @@ class ModelConfig:
     norm: str = "rmsnorm"       # rmsnorm | layernorm
     pattern: Tuple[str, ...] = ("attn",)
     rope_theta: float = 10_000.0
-    window: int = 0             # sliding-window width for 'local' blocks
+    yarn: Optional[YarnConfig] = None  # full-attention layers' rope scaling
+    window: int = 0             # sliding-window width of WINDOW_KINDS
     moe: Optional[MoEConfig] = None
     dense_residual_ff: int = 0  # Arctic: parallel dense FFN next to the MoE
     cross_len: int = 0          # stub encoder sequence length (VLM patches /
@@ -107,6 +127,13 @@ class ModelConfig:
             raise ValueError("n_heads must be a multiple of n_kv")
         if "xattn" in self.pattern and self.cross_len == 0:
             raise ValueError("xattn blocks need cross_len > 0")
+        if set(MOE_KINDS) & set(self.pattern) and self.moe is None:
+            raise ValueError("MoE blocks need a MoEConfig")
+        if self.moe is not None and not (
+                0 <= self.moe.first_held and
+                self.moe.first_held + self.moe.n_held
+                <= self.moe.num_experts):
+            raise ValueError("held experts lie outside the router's")
 
     @property
     def n_groups(self) -> int:
@@ -144,7 +171,8 @@ class ModelConfig:
     @property
     def sub_quadratic(self) -> bool:
         """Eligible for long_500k: every block is O(seq) at decode."""
-        return all(k in ("rglru", "slstm", "mlstm", "local") for k in self.pattern)
+        return all(k in ("rglru", "slstm", "mlstm") + WINDOW_KINDS
+                   for k in self.pattern)
 
     def replace(self, **kw) -> "ModelConfig":
         return dataclasses.replace(self, **kw)
@@ -158,7 +186,7 @@ class ModelConfig:
             moe = dataclasses.replace(
                 self.moe, num_experts=4, top_k=2,
                 num_shared=min(self.moe.num_shared, 1), d_expert=32,
-                group_size=64)
+                held=0, first_held=0)
         n_kv = min(self.n_kv, 2)
         n_heads = max(4 // n_kv * n_kv, n_kv)
         return self.replace(

@@ -15,7 +15,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.parallel.sharding import current_mesh, role_size, shard
-from .config import ModelConfig
+from .config import ModelConfig, YarnConfig
 
 Params = Dict[str, jnp.ndarray]
 
@@ -91,14 +91,39 @@ def rope_freqs(head_dim: int, theta: float) -> jnp.ndarray:
                             / head_dim))
 
 
-def apply_rope(x: jnp.ndarray, positions: jnp.ndarray,
-               theta: float) -> jnp.ndarray:
-    """x: (..., S, H, D); positions: broadcastable to (..., S)."""
+def yarn_freqs(head_dim: int, theta: float, yarn: YarnConfig) -> jnp.ndarray:
+    """YaRN's blend of interpolated and original frequencies, as the HF
+    rope utilities' ``_compute_yarn_parameters`` (truncated correction
+    range): pair i keeps ``1 / theta^(2i/d)`` below the correction range,
+    takes it divided by ``factor`` above it, and a linear ramp between."""
+    def corr_dim(rotations):
+        return (head_dim * math.log(yarn.original_max_position
+                                    / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(corr_dim(yarn.beta_fast)), 0)
+    high = min(math.ceil(corr_dim(yarn.beta_slow)), head_dim - 1)
+    if low == high:
+        high += 0.001
+    extra = rope_freqs(head_dim, theta)
+    ramp = jnp.clip((jnp.arange(head_dim // 2, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    return extra / yarn.factor * ramp + extra * (1.0 - ramp)
+
+
+def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float,
+               yarn: Optional[YarnConfig] = None) -> jnp.ndarray:
+    """x: (..., S, H, D); positions: broadcastable to (..., S).  With
+    ``yarn``, YaRN's frequencies, and cos and sin scaled by its
+    ``attention_factor``."""
     d = x.shape[-1]
-    freqs = rope_freqs(d, theta)                      # (D/2,)
+    freqs = (rope_freqs(d, theta) if yarn is None
+             else yarn_freqs(d, theta, yarn))          # (D/2,)
     angles = positions[..., None].astype(jnp.float32) * freqs  # (..., S, D/2)
     angles = angles[..., None, :]                     # (..., S, 1, D/2)
     cos, sin = jnp.cos(angles), jnp.sin(angles)
+    if yarn is not None:
+        cos, sin = cos * yarn.attention_factor, sin * yarn.attention_factor
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)
@@ -268,6 +293,13 @@ def causal_mask(s: int, t: int, window: int = 0,
     return m[None, None]
 
 
+def one_device() -> bool:
+    """No mesh, or a mesh of one device: where a Pallas kernel may run as
+    it is (Mosaic kernels have no partitioning rule)."""
+    mc = current_mesh()
+    return mc is None or mc.mesh.size == 1
+
+
 # Shortest sequence at which the fused kernel beats the naive score chain
 # on a TPU v5e, forward, remat recompute and backward (chip sweep over the
 # zoo's head layouts, PERF.md section 5): from 512 where a kv head serves a
@@ -277,24 +309,28 @@ FUSED_ATTENTION_MIN_SEQ = 512
 FUSED_ATTENTION_MIN_SEQ_MHA = 1024
 
 
-def fused_attention_fits(q: jnp.ndarray, k: jnp.ndarray, causal: bool,
-                         window: int) -> bool:
+def fused_attention_fits(q: jnp.ndarray, k: jnp.ndarray,
+                         causal: bool) -> bool:
     """Self-attention over q: (B, S, H, D), k: (B, S, Kv, D) takes the
     fused Pallas kernel (``kernels.ops.flash_attention``) on a TPU lowering:
-    causal with no window, S at least the threshold above for its group,
-    operands held whole by one device (the kernel has no partitioning
-    rule), and a shape the kernel's tiles fit
-    (``kernels.flash_attention.fits``)."""
+    causal, over the whole sequence or a sliding window (the kernel skips
+    the tiles outside it), S at least the threshold above for its group,
+    operands held whole by one device (``one_device``), and a shape the
+    kernel's tiles fit (``kernels.flash_attention.fits``)."""
     s, group = q.shape[1], q.shape[2] // k.shape[2]
     min_seq = (FUSED_ATTENTION_MIN_SEQ_MHA if group == 1
                else FUSED_ATTENTION_MIN_SEQ)
-    mc = current_mesh()
-    if not (causal and window == 0 and s >= min_seq
-            and (mc is None or mc.mesh.size == 1)):
+    if not (causal and s >= min_seq and one_device()):
         return False
     # imported only here: Pallas adds a second to the start of a process
     from repro.kernels.flash_attention import fits
     return fits(s, s, group, q.shape[3])
+
+
+def rope_yarn(cfg: ModelConfig, window: int) -> Optional[YarnConfig]:
+    """The YaRN scaling of a layer's rope: the config's for a layer over the
+    whole sequence, none for a sliding-window layer."""
+    return cfg.yarn if window == 0 else None
 
 
 @scoped("attention")
@@ -302,11 +338,13 @@ def attention_block(p: Params, x: jnp.ndarray, cfg: ModelConfig,
                     positions: jnp.ndarray, window: int = 0,
                     use_rope: bool = True,
                     causal: bool = True) -> jnp.ndarray:
-    """Self-attention over x: (B, S, d)."""
+    """Self-attention over x: (B, S, d); full-sequence layers take the
+    config's YaRN scaling, if any (``rope_yarn``)."""
     q, k, v = _qkv(p, x, x)
     if use_rope:
-        q = apply_rope(q, positions, cfg.rope_theta)
-        k = apply_rope(k, positions, cfg.rope_theta)
+        yarn = rope_yarn(cfg, window)
+        q = apply_rope(q, positions, cfg.rope_theta, yarn)
+        k = apply_rope(k, positions, cfg.rope_theta, yarn)
     q, k, v = _shard_q(q), _shard_kv(k), _shard_kv(v)
 
     def unfused(q, k, v):
@@ -317,11 +355,12 @@ def attention_block(p: Params, x: jnp.ndarray, cfg: ModelConfig,
                 if causal else None)
         return mha_logits_to_out(q, k, v, mask, cfg)
 
-    if fused_attention_fits(q, k, causal, window):
+    if fused_attention_fits(q, k, causal):
         from repro.kernels.ops import flash_attention
         out = jax.lax.platform_dependent(
             q, k, v,
-            tpu=lambda q, k, v: flash_attention(q, k, v, interpret=False),
+            tpu=lambda q, k, v: flash_attention(q, k, v, window=window,
+                                                interpret=False),
             default=unfused)
     else:
         out = unfused(q, k, v)
@@ -370,8 +409,9 @@ def decode_attention(p: Params, x: jnp.ndarray, cache_k: jnp.ndarray,
     q, k, v = _qkv(p, x, x)
     if use_rope:
         ppos = jnp.full((x.shape[0], 1), pos, jnp.int32)
-        q = apply_rope(q, ppos, cfg.rope_theta)
-        k = apply_rope(k, ppos, cfg.rope_theta)
+        yarn = rope_yarn(cfg, window)
+        q = apply_rope(q, ppos, cfg.rope_theta, yarn)
+        k = apply_rope(k, ppos, cfg.rope_theta, yarn)
     s_cache = cache_k.shape[1]
     slot = jnp.where(window > 0, pos % jnp.maximum(s_cache, 1), pos)
     ck = jax.lax.dynamic_update_slice(cache_k, k.astype(cache_k.dtype),

@@ -1,41 +1,55 @@
-"""Mixture-of-Experts FFN: shared experts + routed top-k with capacity.
+"""Mixture-of-Experts FFN: routed top-k experts, dropless, over the block of
+experts this layer holds (+ shared experts).
 
-Mesh-TensorFlow/T5X-style einsum dispatch: tokens are split into groups of
-``group_size``; within a group each token picks its top-k experts, positions
-are assigned up to a per-expert capacity ``C = ceil(G * k * cf / E)``, and
-dispatch/combine are dense einsums (MXU-friendly, shardable: the expert dim
-partitions over the ``model`` axis => the resharding between the token and
-expert layouts lowers to an all-to-all on TPU).
+The router scores every routed expert (softmax over ``num_experts``); each
+token takes its ``top_k`` and renormalises their gates to sum to one.  A
+layer holds a contiguous block of the experts, ``MoEConfig.held`` from
+``first_held`` (all of them by default): under expert parallelism, one
+chip's share.  It computes, for every (token, expert) assignment whose
+expert it holds, ``gate * SwiGLU_e(x)``, and nothing for the others, whose
+part of the result the chips holding them add.
 
-Covers both assigned MoE archs:
+No token is dropped.  The assignments are sorted by expert into a buffer
+that holds every assignment that can land here, ``T * min(k, held)`` rows,
+and the held experts run as grouped matrix products over their own rows of
+it (``_grouped``).  The load-balancing and z-losses are computed over all
+the router's experts.
+
+Covers the registry's MoE archs:
   * deepseek-moe-16b — 64 routed top-6 + 2 shared experts (fine-grained);
   * arctic-480b — 128 routed top-2 + parallel dense residual FFN
-    (``dense_residual_ff``; handled by the caller in transformer.py).
+    (``dense_residual_ff``; handled by the caller in transformer.py);
+  * mellum2-12b — 64 routed top-8, sliding and full attention layers.
 """
 from __future__ import annotations
 
-import math
+import functools
 from typing import Dict, Tuple
 
 import jax
 import jax.numpy as jnp
 
-from repro.parallel.sharding import shard
 from .config import ModelConfig
-from .layers import Params, apply_mlp, dense_init, init_mlp, scoped
+from .layers import Params, apply_mlp, dense_init, init_mlp, one_device, scoped
+
+HIGHEST = jax.lax.Precision.HIGHEST
+# The grouped products' tiles: rows, then the contracted and output widths,
+# each the largest multiple of 128 up to this that divides the width.
+ROW_TILE = 512
+WIDTH_TILE = 1024
 
 
 def init_moe(key, cfg: ModelConfig) -> Params:
     assert cfg.moe is not None
     m = cfg.moe
-    d, f = cfg.d_model, cfg.d_expert_eff
+    d, f, n = cfg.d_model, cfg.d_expert_eff, m.n_held
     ks = jax.random.split(key, 5)
     p: Params = {
         "router": {"w": dense_init(ks[0], (d, m.num_experts))},
         "experts": {
-            "wi": dense_init(ks[1], (m.num_experts, d, f)),
-            "wg": dense_init(ks[2], (m.num_experts, d, f)),
-            "wo": dense_init(ks[3], (m.num_experts, f, d)),
+            "wi": dense_init(ks[1], (n, d, f)),
+            "wg": dense_init(ks[2], (n, d, f)),
+            "wo": dense_init(ks[3], (n, f, d)),
         },
     }
     if m.num_shared > 0:
@@ -43,81 +57,142 @@ def init_moe(key, cfg: ModelConfig) -> Params:
     return p
 
 
-def capacity(cfg: ModelConfig, group: int) -> int:
+def _width_tile(dim: int) -> int:
+    if dim <= WIDTH_TILE:
+        return dim
+    for t in range(WIDTH_TILE, 127, -128):
+        if dim % t == 0:
+            return t
+    return WIDTH_TILE
+
+
+def _tiling(m: int, k: int, n: int) -> Tuple[int, int, int]:
+    return min(ROW_TILE, m), _width_tile(k), _width_tile(n)
+
+
+def _grouped(x: jnp.ndarray, w: jnp.ndarray,
+             sizes: jnp.ndarray) -> jnp.ndarray:
+    """Rows of ``x`` (R, K), sorted by expert, times their expert's matrix
+    of ``w`` (n, K, N).  ``sizes`` (n + 1,) counts the rows of each held
+    expert, then the rows past them, which come out zero.
+
+    One device: the Pallas grouped matmul (megablox ``gmm``, Mosaic on a
+    TPU lowering, interpreted on the CPU), whose grid visits only the held
+    experts' row tiles.  A mesh of several devices takes
+    ``lax.ragged_dot``, since a Mosaic kernel has no partitioning rule: it
+    gets a group for every row, the rows past the held experts' times a
+    zero matrix, and accumulates in float32 as the kernel does."""
+    if not one_device():
+        w = jnp.concatenate([w, jnp.zeros((1,) + w.shape[1:], w.dtype)])
+        return jax.lax.ragged_dot(x, w, sizes,
+                                  preferred_element_type=jnp.float32
+                                  ).astype(x.dtype)
+    # imported only here: Pallas adds a second to the start of a process
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+
+    def run(x, w, sizes, interpret):
+        return gmm(x, w, sizes, x.dtype, _tiling, None, None, False,
+                   interpret)
+
+    return jax.lax.platform_dependent(
+        x, w, sizes,
+        cpu=lambda x, w, sizes: run(x, w, sizes, True),
+        default=lambda x, w, sizes: run(x, w, sizes, False))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _gather_rows(src: jnp.ndarray, idx: jnp.ndarray, inv: jnp.ndarray,
+                 rep: int) -> jnp.ndarray:
+    """Row p of the result is ``src[idx[p] // rep]``, zero where that lies
+    past ``src``.  ``inv`` inverts ``idx`` (``idx[inv[i]] == i`` wherever
+    ``inv[i]`` is a row of the result), so the transpose is a gather too and
+    no scatter runs: row q of ``src`` gets the sum of the cotangents of the
+    ``rep`` rows that read it, ``inv[q * rep + j]``."""
+    return jnp.take(src, idx // rep, axis=0, mode="fill", fill_value=0)
+
+
+def _gather_rows_fwd(src, idx, inv, rep):
+    return _gather_rows(src, idx, inv, rep), (inv, src.shape[0])
+
+
+def _gather_rows_bwd(rep, res, dout):
+    inv, n = res
+    dsrc = jnp.take(dout, inv, axis=0, mode="fill", fill_value=0)
+    return dsrc.reshape(n, rep, -1).sum(axis=1), None, None
+
+
+_gather_rows.defvjp(_gather_rows_fwd, _gather_rows_bwd)
+
+
+def _buffer_rows(cfg: ModelConfig, tokens: int) -> int:
+    """Rows of the sorted buffer: every assignment that can land on the
+    held experts (a token's k experts are distinct), padded to whole row
+    tiles."""
     m = cfg.moe
-    c = int(math.ceil(group * m.top_k * m.capacity_factor / m.num_experts))
-    return max(c, 1)
+    rows = tokens * min(m.top_k, m.n_held)
+    tile = min(ROW_TILE, -(-rows // 16) * 16)
+    return -(-rows // tile) * tile
 
 
 @scoped("moe")
 def apply_moe(p: Params, x: jnp.ndarray,
               cfg: ModelConfig) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
-    """x: (B, S, d) -> (out, aux_losses).
+    """x: (B, S, d) -> (out, aux).
 
-    aux: ``aux_loss`` (load-balancing, Shazeer-style) and ``z_loss``.
+    aux: ``aux_loss`` (load-balancing, Shazeer-style) and ``z_loss`` over
+    all the router's experts, and ``expert_counts`` (held,): the
+    assignments each held expert received.
     """
     m = cfg.moe
     b, s, d = x.shape
-    t = b * s
-    g = min(m.group_size, t)
-    n_groups = max(t // g, 1)
-    g = t // n_groups  # exact split (t divisible in all our shapes)
-    xg = x.reshape(n_groups, g, d)
-
-    logits = jnp.einsum("ngd,de->nge", xg, p["router"]["w"].astype(x.dtype))
-    logits = logits.astype(jnp.float32)
-    probs = jax.nn.softmax(logits, axis=-1)
-
-    # aux losses (computed over all tokens)
-    z = jax.scipy.special.logsumexp(logits, axis=-1)
-    z_loss = m.router_z_coef * jnp.mean(jnp.square(z))
-    me = jnp.mean(probs.reshape(-1, m.num_experts), axis=0)
-
-    gate_vals, gate_idx = jax.lax.top_k(probs, m.top_k)   # (n, g, k)
-    gate_vals = gate_vals / jnp.maximum(
-        jnp.sum(gate_vals, axis=-1, keepdims=True), 1e-9)
-
-    cap = capacity(cfg, g)
-    # one-hot expert assignment per (token, k): (n, g, k, E)
-    assign = jax.nn.one_hot(gate_idx, m.num_experts, dtype=jnp.float32)
-    ce = jnp.mean(jnp.sum(assign, axis=2).reshape(-1, m.num_experts), axis=0)
-    aux_loss = m.aux_coef * m.num_experts * jnp.sum(me * ce)
-
-    # position within each expert's buffer, k-major then token order
-    # (n, g*k, E) flattened so ranks interleave across k slots correctly
-    assign_fl = assign.transpose(0, 2, 1, 3).reshape(n_groups, -1,
-                                                     m.num_experts)
-    pos = jnp.cumsum(assign_fl, axis=1) * assign_fl - 1.0   # (n, g*k, E)
-    keep = (pos >= 0) & (pos < cap)
-    pos = jnp.where(keep, pos, 0.0)
-    onehot_pos = jax.nn.one_hot(pos.astype(jnp.int32), cap,
-                                dtype=jnp.float32) * keep[..., None]
-    # back to (n, k, g, E, C) -> (n, g, k, E, C)
-    disp = onehot_pos.reshape(n_groups, m.top_k, g, m.num_experts, cap)
-    disp = disp.transpose(0, 2, 1, 3, 4)
-    combine = disp * gate_vals[..., None, None]              # weighted
-    dispatch = jnp.sum(disp, axis=2)                         # (n, g, E, C)
-    combine = jnp.sum(combine, axis=2)                       # (n, g, E, C)
-
+    t, k, n = b * s, m.top_k, m.n_held
     dt = x.dtype
-    spec = "moe_ecd_grouped" if m.dispatch_local else "moe_ecd"
-    expert_in = jnp.einsum("ngd,ngec->necd", xg,
-                           dispatch.astype(dt))              # (n, E, C, d)
-    expert_in = shard(expert_in, spec)
-    w = p["experts"]
-    h = jnp.einsum("necd,edf->necf", expert_in, w["wi"].astype(dt))
-    gte = jnp.einsum("necd,edf->necf", expert_in, w["wg"].astype(dt))
-    h = jax.nn.silu(gte) * h
-    eout = jnp.einsum("necf,efd->necd", h, w["wo"].astype(dt))
-    # NOTE(§Perf iter 2, REFUTED): re-sharding eout back to group-local
-    # before the combine made XLA all-gather the expert outputs (340 GB) —
-    # worse than the all-reduce it removed. Keep the expert layout here.
-    eout = shard(eout, spec)
-    out = jnp.einsum("necd,ngec->ngd", eout, combine.astype(dt))
+    xt = x.reshape(t, d)
+
+    with jax.named_scope("router"):
+        logits = jnp.einsum("td,de->te", xt.astype(jnp.float32),
+                            p["router"]["w"], precision=HIGHEST)
+        probs = jax.nn.softmax(logits, axis=-1)
+        z = jax.scipy.special.logsumexp(logits, axis=-1)
+        z_loss = m.router_z_coef * jnp.mean(jnp.square(z))
+        gate, idx = jax.lax.top_k(probs, k)                  # (T, k)
+        gate = gate / jnp.sum(gate, axis=-1, keepdims=True)
+        counts = jnp.sum(jax.nn.one_hot(idx, m.num_experts,
+                                        dtype=jnp.float32), axis=(0, 1))
+        aux_loss = m.aux_coef * m.num_experts * jnp.sum(
+            jnp.mean(probs, axis=0) * counts / t)
+
+    with jax.named_scope("dispatch"):
+        local = idx.reshape(-1) - m.first_held               # (T k,)
+        held = (local >= 0) & (local < n)
+        order = jnp.argsort(jnp.where(held, local, n), stable=True)
+        # assignment a's row of the buffer; the buffer's row r holds
+        # assignment order[r], or none (t * k) past the assignments
+        slot = jnp.zeros((t * k,), jnp.int32).at[order].set(
+            jnp.arange(t * k, dtype=jnp.int32), unique_indices=True)
+        rows = _buffer_rows(cfg, t)
+        order = jnp.pad(order, (0, max(rows - t * k, 0)),
+                        constant_values=t * k)[:rows]
+        held_counts = counts[m.first_held:m.first_held + n].astype(jnp.int32)
+        sizes = jnp.concatenate(
+            [held_counts, (rows - jnp.sum(held_counts))[None]])
+        xs = _gather_rows(xt, order, slot, k)                # (rows, d)
+
+    with jax.named_scope("experts"):
+        w = p["experts"]
+        h = _grouped(xs, w["wi"].astype(dt), sizes)
+        g = _grouped(xs, w["wg"].astype(dt), sizes)
+        ys = _grouped(jax.nn.silu(g) * h, w["wo"].astype(dt), sizes)
+
+    with jax.named_scope("combine"):
+        y = _gather_rows(ys, slot, order, 1)                 # (T k, d)
+        y = jnp.where(held[:, None], y, 0).reshape(t, k, d)
+        out = jnp.einsum("tkd,tk->td", y, gate.astype(dt),
+                         preferred_element_type=jnp.float32).astype(dt)
 
     out = out.reshape(b, s, d)
     if "shared" in p:
         out = out + apply_mlp(p["shared"], x, cfg)
     aux = {"aux_loss": aux_loss, "z_loss": z_loss,
-           "expert_load": me}
+           "expert_counts": held_counts}
     return out, aux

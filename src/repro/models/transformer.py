@@ -23,7 +23,7 @@ import jax.numpy as jnp
 
 from repro.parallel.sharding import shard
 from . import recurrent as rec
-from .config import ModelConfig
+from .config import MOE_KINDS, WINDOW_KINDS, ModelConfig
 from .layers import (Params, apply_mlp, apply_norm, attention_block,
                      cross_attention_block, decode_attention,
                      dense_init, embed_init, init_attention, init_mlp,
@@ -31,6 +31,7 @@ from .layers import (Params, apply_mlp, apply_norm, attention_block,
 from .moe import apply_moe, init_moe
 
 Batch = Dict[str, jnp.ndarray]
+SELF_ATTENTION_KINDS = ("attn", "local", "moe", "local_moe", "encdec")
 
 
 # ---------------------------------------------------------------------------
@@ -41,7 +42,7 @@ Batch = Dict[str, jnp.ndarray]
 def _init_block(key, kind: str, cfg: ModelConfig) -> Params:
     ks = jax.random.split(key, 6)
     p: Params = {"norm1": init_norm(cfg)}
-    if kind in ("attn", "local", "moe", "encdec"):
+    if kind in SELF_ATTENTION_KINDS:
         p["attn"] = init_attention(ks[0], cfg)
     if kind == "encdec":
         p["norm_x"] = init_norm(cfg)
@@ -54,7 +55,7 @@ def _init_block(key, kind: str, cfg: ModelConfig) -> Params:
         p["slstm"] = rec.init_slstm(ks[2], cfg)
     if kind == "mlstm":
         p["mlstm"] = rec.init_mlstm(ks[2], cfg)
-    if kind == "moe":
+    if kind in MOE_KINDS:
         p["norm2"] = init_norm(cfg)
         p["moe"] = init_moe(ks[3], cfg)
         if cfg.dense_residual_ff:
@@ -65,17 +66,37 @@ def _init_block(key, kind: str, cfg: ModelConfig) -> Params:
     return p
 
 
-def _zero_aux() -> Dict[str, jnp.ndarray]:
-    return {"aux_loss": jnp.zeros((), jnp.float32),
-            "z_loss": jnp.zeros((), jnp.float32)}
+def _zero_aux(cfg: ModelConfig) -> Dict[str, jnp.ndarray]:
+    """The losses MoE layers add, and with experts the assignments each
+    held expert received, summed over the layers."""
+    aux = {"aux_loss": jnp.zeros((), jnp.float32),
+           "z_loss": jnp.zeros((), jnp.float32)}
+    if cfg.moe is not None:
+        aux["expert_counts"] = jnp.zeros((cfg.moe.n_held,), jnp.int32)
+    return aux
+
+
+def _apply_ffn(kind: str, p: Params, x: jnp.ndarray, cfg: ModelConfig,
+               aux: Dict) -> Tuple[jnp.ndarray, Dict]:
+    """The block's feed-forward half, added to the residual: the MoE FFN
+    (its aux losses and counts replace ``aux``), or the MLP."""
+    if kind in MOE_KINDS:
+        h = apply_norm(p["norm2"], x, cfg)
+        moe_out, aux = apply_moe(p["moe"], h, cfg)
+        if "dense_ff" in p:
+            moe_out = moe_out + apply_mlp(p["dense_ff"], h, cfg)
+        x = x + moe_out
+    elif "mlp" in p:
+        x = x + apply_mlp(p["mlp"], apply_norm(p["norm2"], x, cfg), cfg)
+    return x, aux
 
 
 def _apply_block(kind: str, p: Params, x: jnp.ndarray, cfg: ModelConfig,
                  positions: jnp.ndarray,
                  enc: Optional[jnp.ndarray]) -> Tuple[jnp.ndarray, Dict]:
-    aux = _zero_aux()
-    if kind in ("attn", "local", "moe", "encdec"):
-        w = cfg.window if kind == "local" else 0
+    aux = _zero_aux(cfg)
+    if kind in SELF_ATTENTION_KINDS:
+        w = cfg.window if kind in WINDOW_KINDS else 0
         x = x + attention_block(p["attn"], apply_norm(p["norm1"], x, cfg),
                                 cfg, positions, window=w,
                                 use_rope=(cfg.rope_theta > 0))
@@ -95,15 +116,7 @@ def _apply_block(kind: str, p: Params, x: jnp.ndarray, cfg: ModelConfig,
     if kind == "mlstm":
         x = x + rec.apply_mlstm(p["mlstm"], apply_norm(p["norm1"], x, cfg),
                                 cfg)
-    if kind == "moe":
-        h = apply_norm(p["norm2"], x, cfg)
-        moe_out, moe_aux = apply_moe(p["moe"], h, cfg)
-        if "dense_ff" in p:
-            moe_out = moe_out + apply_mlp(p["dense_ff"], h, cfg)
-        x = x + moe_out
-        aux = {"aux_loss": moe_aux["aux_loss"], "z_loss": moe_aux["z_loss"]}
-    elif "mlp" in p:
-        x = x + apply_mlp(p["mlp"], apply_norm(p["norm2"], x, cfg), cfg)
+    x, aux = _apply_ffn(kind, p, x, cfg, aux)
     x = shard(x, "act_seq" if cfg.seq_parallel_residual else "act_btd")
     return x, aux
 
@@ -195,17 +208,18 @@ def param_count(cfg: ModelConfig) -> int:
 
 
 def active_param_count(cfg: ModelConfig) -> int:
-    """Parameters touched per token (MoE: shared + top_k routed experts)."""
+    """Parameters touched per token (MoE: shared + the routed experts a
+    token takes among those held, top_k * held / num_experts of them on
+    average)."""
     total = param_count(cfg)
     if cfg.moe is None:
         return total
     m = cfg.moe
-    f = cfg.d_expert_eff
-    per_expert = 3 * cfg.d_model * f
-    n_moe_layers = sum(1 for k in cfg.pattern for _ in range(cfg.n_groups)
-                      if k == "moe") + sum(1 for k in cfg.tail_pattern
-                                           if k == "moe")
-    inactive = n_moe_layers * (m.num_experts - m.top_k) * per_expert
+    per_expert = 3 * cfg.d_model * cfg.d_expert_eff
+    n_moe_layers = (cfg.n_groups * sum(k in MOE_KINDS for k in cfg.pattern)
+                    + sum(k in MOE_KINDS for k in cfg.tail_pattern))
+    inactive = (n_moe_layers * per_expert * m.n_held
+                * (m.num_experts - m.top_k) // m.num_experts)
     return total - inactive
 
 
@@ -239,22 +253,26 @@ def forward(params: Params, batch: Batch,
     if enc is not None:
         enc = enc.astype(dt)
 
-    aux_total = _zero_aux()
+    aux_total = _zero_aux(cfg)
+
+    def block(kind, p, x):
+        return _apply_block(kind, p, x, cfg, positions, enc)
+
+    if cfg.remat:
+        # each block rematerialised on its own, so that the backward pass
+        # holds one block's intermediates at a time, not a whole group's
+        block = jax.checkpoint(block, static_argnums=(0,),
+                               policy=jax.checkpoint_policies.nothing_saveable)
 
     def group_body(carry, gp):
         x, aux = carry
         for si, kind in enumerate(cfg.pattern):
-            x, a = _apply_block(kind, gp[f"s{si}_{kind}"], x, cfg,
-                                positions, enc)
+            x, a = block(kind, gp[f"s{si}_{kind}"], x)
             aux = jax.tree_util.tree_map(jnp.add, aux, a)
         return (x, aux), None
 
     if cfg.n_groups > 0:
-        body = group_body
-        if cfg.remat:
-            body = jax.checkpoint(group_body,
-                                  policy=jax.checkpoint_policies.nothing_saveable)
-        (x, aux_total), _ = jax.lax.scan(body, (x, aux_total),
+        (x, aux_total), _ = jax.lax.scan(group_body, (x, aux_total),
                                          params["scan"])
     for si, kind in enumerate(cfg.tail_pattern):
         x, a = _apply_block(kind, params["tail"][f"t{si}_{kind}"], x, cfg,
@@ -306,14 +324,9 @@ def loss_fn(params: Params, batch: Batch,
 def _slot_state(kind: str, cfg: ModelConfig, batch: int,
                 max_len: int) -> Params:
     dt = jnp.dtype(cfg.dtype)
-    if kind in ("attn", "moe", "encdec"):
-        s = max_len
-    elif kind == "local":
-        s = min(max_len, cfg.window)
-    else:
-        s = 0
+    s = min(max_len, cfg.window) if kind in WINDOW_KINDS else max_len
     st: Params = {}
-    if kind in ("attn", "local", "moe", "encdec"):
+    if kind in SELF_ATTENTION_KINDS:
         st["k"] = jnp.zeros((batch, s, cfg.n_kv, cfg.head_dim), dt)
         st["v"] = jnp.zeros((batch, s, cfg.n_kv, cfg.head_dim), dt)
     if kind in ("xattn", "encdec"):
@@ -389,8 +402,8 @@ def _step_block(kind: str, p: Params, x: jnp.ndarray, st: Params,
                 pos: jnp.ndarray, cfg: ModelConfig) -> Tuple[jnp.ndarray,
                                                              Params]:
     new_st = dict(st)
-    if kind in ("attn", "local", "moe", "encdec"):
-        w = cfg.window if kind == "local" else 0
+    if kind in SELF_ATTENTION_KINDS:
+        w = cfg.window if kind in WINDOW_KINDS else 0
         h = apply_norm(p["norm1"], x, cfg)
         y, ck, cv = decode_attention(p["attn"], h, st["k"], st["v"], pos,
                                      cfg, window=w,
@@ -429,14 +442,7 @@ def _step_block(kind: str, p: Params, x: jnp.ndarray, st: Params,
                                {k: st[k] for k in ("C", "n", "m")}, cfg)
         new_st.update(s2)
         x = x + y
-    if kind == "moe":
-        h = apply_norm(p["norm2"], x, cfg)
-        moe_out, _ = apply_moe(p["moe"], h, cfg)
-        if "dense_ff" in p:
-            moe_out = moe_out + apply_mlp(p["dense_ff"], h, cfg)
-        x = x + moe_out
-    elif "mlp" in p:
-        x = x + apply_mlp(p["mlp"], apply_norm(p["norm2"], x, cfg), cfg)
+    x, _ = _apply_ffn(kind, p, x, cfg, {})
     return x, new_st
 
 

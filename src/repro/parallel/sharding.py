@@ -32,7 +32,7 @@ _ctx = threading.local()
 
 # logical activation name -> PartitionSpec builder (axes names resolved late)
 # Conventions: B=batch, S=sequence, H=heads, K=kv-heads, D=head_dim, F=d_ff,
-# E=experts, C=capacity, M=d_model.
+# E=experts, M=d_model.
 _ACT_SPECS: Dict[str, Tuple[Optional[str], ...]] = {
     # (B, S, F)
     "act_ff": ("batch", None, "tp"),
@@ -53,16 +53,6 @@ _ACT_SPECS: Dict[str, Tuple[Optional[str], ...]] = {
     # (B, S, K, D) decode KV cache: batch over data, cache seq over model
     # (flash-decoding style partial softmax handled by SPMD partitioner)
     "kv_cache": ("batch", "tp", None, None),
-    # (G, E, C, M) expert dispatch
-    "moe_ecd": (None, "tp", None, None),
-    # hillclimbed variant: groups stay data-sharded through dispatch ->
-    # the (group, expert) resharding lowers to all-to-all, not all-gather
-    "moe_ecd_grouped": ("batch", "tp", None, None),
-    # expert outputs resharded back to group-local (a2a) so the combine
-    # einsum needs no all-reduce over the expert axis
-    "moe_necd_local": ("batch", None, None, None),
-    # (B, S, E) router logits
-    "router": ("batch", None, None),
     # (B, S, R) recurrent width activations
     "act_rnn": ("batch", None, "tp"),
     # (n_slots, B, R) recurrent state

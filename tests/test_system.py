@@ -3,11 +3,11 @@ from repro.configs import ARCH_IDS, SHAPES, get_config, shape_applicable
 
 
 def test_ten_archs_registered():
-    assert len(ARCH_IDS) == 10
+    assert len(ARCH_IDS) == 11
 
 
 def test_every_cell_defined():
-    """40 (arch x shape) cells: each is either applicable or a documented
+    """44 (arch x shape) cells: each is either applicable or a documented
     skip with a reason."""
     n_app, n_skip = 0, 0
     for arch in ARCH_IDS:
@@ -19,8 +19,8 @@ def test_every_cell_defined():
             else:
                 assert reason
                 n_skip += 1
-    assert n_app + n_skip == 40
-    assert n_skip == 8  # long_500k for the 8 full-attention archs
+    assert n_app + n_skip == 44
+    assert n_skip == 9  # long_500k for the 9 archs with full attention
 
 
 def test_smoke_configs_are_small():

@@ -6,6 +6,7 @@ topology is described inside module-scoped fixtures, never at import: only
 one process at a time may load the TPU library, and it keeps it until it
 exits, so every test here compiles in this process.
 """
+import dataclasses
 import math
 import re
 
@@ -20,7 +21,7 @@ from repro.kernels.flash_attention import fits
 from repro.kernels.ops import flash_attention, rglru_scan
 from repro.launch.mesh import make_mesh
 from repro.launch.steps import jit_train_step, train_in_shardings
-from repro.models import layers
+from repro.models import layers, moe
 from repro.optim import make_optimizer
 from repro.parallel import use_mesh
 
@@ -151,7 +152,9 @@ DISPATCH = [
     ("granite_train_4k", 2, 4096, 32, 8, 128, 0, True, 1, True),
     ("granite_train_512", 16, 512, 32, 8, 128, 0, True, 1, True),
     ("cpu_lowering", 2, 4096, 32, 8, 128, 0, True, 0, False),
-    ("windowed", 2, 4096, 32, 8, 128, 2048, True, 1, False),
+    ("windowed", 2, 4096, 32, 8, 128, 2048, True, 1, True),
+    ("mellum_sliding", 8, 4096, 32, 4, 128, 1024, True, 1, True),
+    ("windowed_2x2", 8, 4096, 32, 4, 128, 1024, True, 4, False),
     ("non_causal", 2, 4096, 32, 8, 128, 0, False, 1, False),
     ("below_threshold", 32, 256, 32, 8, 128, 0, True, 1, False),
     ("one_head_a_kv_head_512", 16, 512, 16, 16, 128, 0, True, 1, False),
@@ -191,3 +194,65 @@ def test_attention_dispatch(topo, case, b, s, h, kv, d, window, causal,
     text = jax.jit(block).lower(params, x).as_text()
     assert ("tpu_custom_call" in text) == fused
     assert ("flash_attention_fwd" in text) == fused
+
+
+def mellum_stage():
+    """mellum2-12b as the chip benchmark cuts it: one period of 4 layers,
+    the 8 experts of chip 0 of an 8-chip expert-parallel group, an eighth
+    of the vocabulary."""
+    cfg = get_config("mellum2-12b")
+    return cfg.replace(n_layers=4, vocab=cfg.vocab // 8,
+                       moe=dataclasses.replace(cfg.moe, held=8))
+
+
+@pytest.mark.parametrize("chips,kernel", [(1, "gmm"), (4, "ragged-dot")])
+def test_expert_products_dispatch(topo, chips, kernel):
+    """One chip runs the held experts' products as the Pallas grouped
+    matmul (``gmm``, ``tgmm`` in the backward); a mesh of four, which a
+    Mosaic kernel cannot be partitioned over, as ``lax.ragged_dot``."""
+    cfg = mellum_stage()
+    mesh = make_mesh(topo.devices[:chips])
+    place = NamedSharding(mesh, PartitionSpec())
+    x = jax.ShapeDtypeStruct((2, 512, cfg.d_model), jnp.bfloat16,
+                             sharding=place)
+    shapes = jax.eval_shape(lambda k: moe.init_moe(k, cfg),
+                            jax.random.PRNGKey(0))
+    params = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=place),
+        shapes)
+
+    def loss(p, x):
+        with use_mesh(mesh if chips > 1 else None):
+            y, aux = moe.apply_moe(p, x, cfg)
+        return jnp.sum(y.astype(jnp.float32)) + aux["aux_loss"]
+
+    text = jax.jit(jax.grad(loss)).lower(params, x).compile().as_text()
+    names = set(re.findall(r"%(t?gmm)[.\d]* = ", text))
+    if kernel == "gmm":
+        assert names == {"gmm", "tgmm"} and "ragged-dot" not in text
+    else:
+        assert not names and "ragged-dot" in text
+
+
+def test_mellum_train_step_fits_one_chip(topo):
+    """The mellum2-12b-4l benchmark step, batch 8 x 4096, f32 params +
+    AdamW, on one chip: it fits HBM, the sliding and full layers take the
+    fused attention kernels, the experts the grouped matmul, and no array
+    holds the S x S scores."""
+    cfg = mellum_stage()
+    opt = make_optimizer("adamw", lr=3e-4)
+    mesh = make_mesh(topo.devices[:1])
+    specs = train_batch_specs(cfg, 8, 4096)
+    in_shardings, pshapes, oshapes = train_in_shardings(cfg, opt, specs,
+                                                        mesh)
+    compiled = jit_train_step(cfg, opt, in_shardings, mesh).lower(
+        pshapes, oshapes, specs).compile()
+    m = compiled.memory_analysis()
+    used = (m.argument_size_in_bytes + m.output_size_in_bytes
+            - m.alias_size_in_bytes + m.temp_size_in_bytes)
+    assert used <= V5E_HBM_BYTES, used / 2**30
+    text = compiled.as_text()
+    for kernel in ("flash_attention_fwd", "flash_attention_dq",
+                   "flash_attention_dkv", "gmm", "tgmm"):
+        assert re.search(r"%" + kernel + r"[.\d]* = ", text), kernel
+    assert not _score_buffers(text, 8, cfg.n_kv, 4096)
