@@ -8,7 +8,9 @@ choice itself passes ``interpret=False``.
 
 ``flash_attention``'s custom VJP runs the Pallas backward kernels (dQ and
 dK/dV) on the forward's output and logsumexp.  ``rglru_scan``'s backward is
-an associative scan of the adjoint recurrence.
+an associative scan of the adjoint recurrence.  ``moe_dispatch`` and
+``moe_combine`` move an MoE layer's live rows between token and expert
+order (``moe_rows``); each one's transpose is the other's kernel.
 """
 from __future__ import annotations
 
@@ -17,6 +19,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from . import moe_rows
 from .flash_attention import flash_attention_bwd, flash_attention_fwd
 from .rglru_scan import rglru_scan_fwd
 
@@ -107,3 +110,103 @@ def _rg_bwd(res, g):
 
 
 rglru_scan.defvjp(_rg_fwd, _rg_bwd)
+
+
+# ---------------------------------------------------------------------------
+# MoE dispatch and combine
+# ---------------------------------------------------------------------------
+#
+# ``order`` (T k,) int32: the assignment (token * k + j) at each sorted
+# position, the held ones first; ``live`` () int32: how many are held;
+# ``slot`` (T, k) int32: each assignment's row of the sorted buffer, -1
+# where its expert is not held.  Rows past ``live`` are read by no one, and
+# the kernels leave the whole tiles past it unwritten.  Values move between
+# assignment and row order by sorts: a gather or scatter of T k elements
+# costs more on a TPU.
+
+
+def _first(v, n):
+    """The first n entries of v, zeros past its end."""
+    return jnp.pad(v, (0, max(n - v.shape[0], 0)))[:n]
+
+
+def _to_rows(slot, values, rows):
+    """values (T, k) at their assignments' rows (rows,): the held ones are
+    rows 0..live-1, in slot order."""
+    key = slot.reshape(-1)
+    key = jnp.where(key >= 0, key, key.shape[0] + rows)
+    return _first(jax.lax.sort((key, values.reshape(-1)), num_keys=1)[1],
+                  rows)
+
+
+def _to_assignments(order, values):
+    """values (rows,) of the sorted rows at their assignments (T k,)."""
+    return jax.lax.sort((order, _first(values, order.shape[0])),
+                        num_keys=1)[1]
+
+
+def _dispatch(x, order, live, k, rows):
+    def run(x, index, live, interpret):
+        src = moe_rows.stage_rows(x, x.shape[0], interpret=interpret)
+        return moe_rows.dispatch_rows(src, index, live, dtype=x.dtype,
+                                      interpret=interpret)
+    return _on_platform(run, x, _first(order, rows) // k, live)
+
+
+def _combine(ys, slot, weight, live):
+    def run(ys, slot, weight, live, interpret):
+        src = moe_rows.stage_rows(ys, live, interpret=interpret)
+        return moe_rows.combine_rows(src, slot, weight, dtype=ys.dtype,
+                                     interpret=interpret)
+    return _on_platform(run, ys, slot, weight, live)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def moe_dispatch(x, order, live, slot, rows):
+    """x (T, D) -> (rows, D): row r < live is x's row of assignment
+    ``order[r]``."""
+    return _dispatch(x, order, live, slot.shape[1], rows)
+
+
+def _md_fwd(x, order, live, slot, rows):
+    return _dispatch(x, order, live, slot.shape[1], rows), (live, slot)
+
+
+def _md_bwd(rows, res, dxs):
+    live, slot = res
+    dx = _combine(dxs, slot, (slot >= 0).astype(jnp.float32), live)
+    return dx, None, None, None
+
+
+moe_dispatch.defvjp(_md_fwd, _md_bwd)
+
+
+@jax.custom_vjp
+def moe_combine(ys, gate, order, live, slot):
+    """ys (R, D), gate (T, k) float32 -> (T, D): token t is the float32
+    sum of ``gate[t, j] * ys[slot[t, j]]`` over its held assignments, in
+    ``ys.dtype``."""
+    return _combine(ys, slot, jnp.where(slot >= 0, gate, 0.0), live)
+
+
+def _mc_fwd(ys, gate, order, live, slot):
+    out = _combine(ys, slot, jnp.where(slot >= 0, gate, 0.0), live)
+    return out, (ys, gate, order, live, slot)
+
+
+def _mc_bwd(res, dout):
+    ys, gate, order, live, slot = res
+    rows, k = ys.shape[0], slot.shape[1]
+
+    def run(dout, index, live, scale, ys, interpret):
+        src = moe_rows.stage_rows(dout, dout.shape[0], interpret=interpret)
+        return moe_rows.dispatch_rows(src, index, live, scale, ys,
+                                      dtype=ys.dtype, interpret=interpret)
+
+    dys, dot = _on_platform(run, dout, _first(order, rows) // k, live,
+                            _to_rows(slot, gate, rows), ys)
+    dgate = _to_assignments(order, dot).reshape(slot.shape)
+    return dys, jnp.where(slot >= 0, dgate, 0.0), None, None, None
+
+
+moe_combine.defvjp(_mc_fwd, _mc_bwd)

@@ -15,6 +15,13 @@ and the held experts run as grouped matrix products over their own rows of
 it (``_grouped``).  The load-balancing and z-losses are computed over all
 the router's experts.
 
+Rows move between token order and that buffer in two ways.  On one device
+the Pallas row kernels (``kernels.ops.moe_dispatch``, ``moe_combine``)
+touch only the live rows, the assignments to held experts, and never form
+a (T k, d) array; rows past them are left unwritten, and no product reads
+them.  A mesh of several devices keeps the gathers over the whole buffer
+(``_gather_rows``), since a Mosaic kernel has no partitioning rule.
+
 Covers the registry's MoE archs:
   * deepseek-moe-16b — 64 routed top-6 + 2 shared experts (fine-grained);
   * arctic-480b — 128 routed top-2 + parallel dense residual FFN
@@ -134,6 +141,22 @@ def _buffer_rows(cfg: ModelConfig, tokens: int) -> int:
     return -(-rows // tile) * tile
 
 
+def _sort_by_expert(idx: jnp.ndarray, m):
+    """The (T, k) assignments of ``idx`` sorted by expert, the held ones
+    first: ``order`` (T k,), the assignment (token * k + j) at each sorted
+    position; ``slot`` (T k,), each assignment's position, its inverse;
+    ``held`` (T k,).  Both by sorts: a scatter or gather of T k elements
+    costs more on a TPU."""
+    t, k = idx.shape
+    local = idx.reshape(-1) - m.first_held
+    held = (local >= 0) & (local < m.n_held)
+    iota = jnp.arange(t * k, dtype=jnp.int32)
+    order = jax.lax.sort((jnp.where(held, local, m.n_held), iota),
+                         num_keys=1, is_stable=True)[1]
+    slot = jax.lax.sort((order, iota), num_keys=1)[1]
+    return order, slot, held
+
+
 @scoped("moe")
 def apply_moe(p: Params, x: jnp.ndarray,
               cfg: ModelConfig) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
@@ -148,6 +171,10 @@ def apply_moe(p: Params, x: jnp.ndarray,
     t, k, n = b * s, m.top_k, m.n_held
     dt = x.dtype
     xt = x.reshape(t, d)
+    kernels = one_device()
+    if kernels:
+        # imported only here: Pallas adds a second to the start of a process
+        from repro.kernels import ops
 
     with jax.named_scope("router"):
         logits = jnp.einsum("td,de->te", xt.astype(jnp.float32),
@@ -163,32 +190,46 @@ def apply_moe(p: Params, x: jnp.ndarray,
             jnp.mean(probs, axis=0) * counts / t)
 
     with jax.named_scope("dispatch"):
-        local = idx.reshape(-1) - m.first_held               # (T k,)
-        held = (local >= 0) & (local < n)
-        order = jnp.argsort(jnp.where(held, local, n), stable=True)
-        # assignment a's row of the buffer; the buffer's row r holds
-        # assignment order[r], or none (t * k) past the assignments
-        slot = jnp.zeros((t * k,), jnp.int32).at[order].set(
-            jnp.arange(t * k, dtype=jnp.int32), unique_indices=True)
         rows = _buffer_rows(cfg, t)
-        order = jnp.pad(order, (0, max(rows - t * k, 0)),
-                        constant_values=t * k)[:rows]
+        order, slot, held = _sort_by_expert(idx, m)
         held_counts = counts[m.first_held:m.first_held + n].astype(jnp.int32)
-        sizes = jnp.concatenate(
-            [held_counts, (rows - jnp.sum(held_counts))[None]])
-        xs = _gather_rows(xt, order, slot, k)                # (rows, d)
+        live = jnp.sum(held_counts)
+        sizes = jnp.concatenate([held_counts, (rows - live)[None]])
+        if kernels:
+            slot = jnp.where(held, slot, -1).reshape(t, k)
+        else:
+            # the buffer's row r holds assignment order[r], or none (t * k)
+            order = jnp.pad(order, (0, max(rows - t * k, 0)),
+                            constant_values=t * k)[:rows]
 
     with jax.named_scope("experts"):
-        w = p["experts"]
-        h = _grouped(xs, w["wi"].astype(dt), sizes)
-        g = _grouped(xs, w["wg"].astype(dt), sizes)
-        ys = _grouped(jax.nn.silu(g) * h, w["wo"].astype(dt), sizes)
+        wi, wg, wo = (p["experts"][w].astype(dt) for w in ("wi", "wg", "wo"))
+
+    def first_products(xt, wi, wg):
+        with jax.named_scope("dispatch"):
+            if kernels:
+                xs = ops.moe_dispatch(xt, order, live, slot, rows)
+            else:
+                xs = _gather_rows(xt, order, slot, k)        # (rows, d)
+        with jax.named_scope("experts"):
+            return _grouped(xs, wi, sizes), _grouped(xs, wg, sizes)
+
+    if kernels:
+        # the backward dispatches again for the weight gradients, so the
+        # (rows, d) rows are not held through the layer's backward
+        first_products = jax.checkpoint(first_products)
+    h, g = first_products(xt, wi, wg)
+    with jax.named_scope("experts"):
+        ys = _grouped(jax.nn.silu(g) * h, wo, sizes)
 
     with jax.named_scope("combine"):
-        y = _gather_rows(ys, slot, order, 1)                 # (T k, d)
-        y = jnp.where(held[:, None], y, 0).reshape(t, k, d)
-        out = jnp.einsum("tkd,tk->td", y, gate.astype(dt),
-                         preferred_element_type=jnp.float32).astype(dt)
+        if kernels:
+            out = ops.moe_combine(ys, gate, order, live, slot)
+        else:
+            y = _gather_rows(ys, slot, order, 1)             # (T k, d)
+            y = jnp.where(held[:, None], y, 0).reshape(t, k, d)
+            out = jnp.einsum("tkd,tk->td", y, gate.astype(dt),
+                             preferred_element_type=jnp.float32).astype(dt)
 
     out = out.reshape(b, s, d)
     if "shared" in p:

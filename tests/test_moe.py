@@ -10,6 +10,8 @@ import numpy as np
 import pytest
 
 from repro.configs import get_config
+from repro.kernels import moe_rows, ops
+from repro.models import moe
 from repro.models import (forward, init_decode_state, init_params, loss_fn,
                           serve_step)
 from repro.models.config import MoEConfig, YarnConfig
@@ -321,3 +323,152 @@ def test_yarn_frequencies_at_mellums_numbers():
         rtol=1e-5)
     np.testing.assert_allclose(np.asarray(ref_rope(q, theta, yarn)),
                                np.asarray(r), atol=1e-5)
+
+
+# ---------------------------------------------------------------------------
+# The row kernels against the gathers over the whole buffer
+# ---------------------------------------------------------------------------
+
+
+def old_dispatch(xt, order, slot, k):
+    return moe._gather_rows(xt, order, slot, k)
+
+
+def old_combine(ys, gate, order, slot, held):
+    t, k = gate.shape
+    y = moe._gather_rows(ys, slot, order, 1)
+    y = jnp.where(held[:, None], y, 0).reshape(t, k, -1)
+    return jnp.einsum("tkd,tk->td", y, gate.astype(ys.dtype),
+                      preferred_element_type=jnp.float32).astype(ys.dtype)
+
+
+# (tokens, top k, experts, held, routing): a random router; every expert
+# held; no token on a held expert; every token on the same held experts;
+# T * k not a whole number of row tiles.
+ROUTES = {
+    "held_8_of_64": (48, 8, 64, 8, "random"),
+    "all_held": (40, 6, 64, 64, "random"),
+    "none_held": (48, 4, 16, 4, "outside"),
+    "same_held": (48, 4, 16, 8, "same"),
+    "ragged": (37, 3, 16, 5, "random"),
+}
+
+
+def routed(name, seed=0):
+    t, k, e, held, how = ROUTES[name]
+    m = MoEConfig(num_experts=e, top_k=k, d_expert=8, held=held)
+    keys = jax.random.split(jax.random.PRNGKey(seed), 3)
+    scores = jax.random.uniform(keys[0], (t, e))
+    if how == "outside":
+        scores = scores + (jnp.arange(e) >= held)
+    elif how == "same":
+        scores = scores + 2 * (jnp.arange(e) < k)
+    idx = jax.lax.top_k(scores, k)[1]
+    rows = t * min(k, held)
+    tile = min(moe.ROW_TILE, -(-rows // 16) * 16)
+    rows = -(-rows // tile) * tile
+    order, slot, held_mask = moe._sort_by_expert(idx, m)
+    gate = jax.random.uniform(keys[1], (t, k), minval=0.1)
+    gate = gate / jnp.sum(gate, axis=1, keepdims=True)
+    live = jnp.sum(held_mask, dtype=jnp.int32)
+    return order, slot, held_mask, live, gate, rows
+
+
+@pytest.mark.parametrize("route", list(ROUTES))
+def test_the_row_kernels_match_the_gathers(route):
+    """Dispatch equals the gathers exactly on the live rows; combine
+    agrees to float32 accumulation; the gradients of x, of the expert rows
+    and of the gates agree with ``jax.grad`` of the gathers."""
+    order, slot, held, live, gate, rows = routed(route)
+    t, k = gate.shape
+    d = 24
+    xt = jax.random.normal(jax.random.PRNGKey(1), (t, d))
+    ys = jax.random.normal(jax.random.PRNGKey(2), (rows, d))
+    cot = jax.random.normal(jax.random.PRNGKey(3), (t, d))
+    held_slot = jnp.where(held, slot, -1).reshape(t, k)
+    n = int(live)
+    assert n == {"none_held": 0, "same_held": t * k}.get(route, n)
+    # the gathers' buffer: row r holds assignment order[r], or none
+    in_rows = jnp.pad(order, (0, max(rows - t * k, 0)),
+                      constant_values=t * k)[:rows]
+
+    xs = ops.moe_dispatch(xt, order, live, held_slot, rows)
+    want = old_dispatch(xt, in_rows, slot, k)
+    np.testing.assert_array_equal(np.asarray(xs)[:n], np.asarray(want)[:n])
+    # the tile that straddles live is zero past it
+    tile = math.gcd(rows, moe_rows.ROW_TILE)
+    end = max(-(-n // tile), 1) * tile
+    assert not np.any(np.asarray(xs)[n:end])
+
+    out = ops.moe_combine(ys, gate, order, live, held_slot)
+    np.testing.assert_allclose(
+        np.asarray(out),
+        np.asarray(old_combine(ys, gate, in_rows, slot, held)),
+        atol=1e-6, rtol=1e-6)
+
+    def new(xt, ys, gate):
+        xs = ops.moe_dispatch(xt, order, live, held_slot, rows)
+        y = ops.moe_combine(ys, gate, order, live, held_slot)
+        return jnp.sum(xs[:n] * ys[:n]) + jnp.sum(y * cot)
+
+    def old(xt, ys, gate):
+        xs = old_dispatch(xt, in_rows, slot, k)
+        y = old_combine(ys, gate, in_rows, slot, held)
+        return jnp.sum(xs[:n] * ys[:n]) + jnp.sum(y * cot)
+
+    got = jax.grad(new, (0, 1, 2))(xt, ys, gate)
+    ref = jax.grad(old, (0, 1, 2))(xt, ys, gate)
+    for name, g, r in zip(("dx", "dys", "dgate"), got, ref):
+        g, r = np.asarray(g), np.asarray(r)
+        if name == "dys":       # rows past live: unwritten, read by no one
+            g, r = g[:n], r[:n]
+        np.testing.assert_allclose(g, r, atol=1e-5, rtol=1e-5, err_msg=name)
+
+
+@pytest.mark.parametrize("kernel", ["dispatch_rows", "stage_rows"])
+def test_no_one_reads_the_tiles_past_the_live_rows(monkeypatch, kernel):
+    """Every whole row tile past the live rows, which the kernels leave
+    unwritten, poisoned with NaN: the layer's output and every gradient
+    stay finite and equal."""
+    cfg = layer_cfg(held=2, first_held=6)
+    p = share(layer_params(seed=3), 6, 2)
+    x = jax.random.normal(jax.random.PRNGKey(4), (2, 24, cfg.d_model))
+
+    def prog(p, x):
+        y, aux = apply_moe(p, x, cfg)
+        return jnp.sum(jnp.sin(y)) + aux["aux_loss"], y
+
+    clean = jax.value_and_grad(prog, (0, 1), has_aux=True)(p, x)
+    real = getattr(moe_rows, kernel)
+    poisoned = []
+
+    def poison(out, live, rows, words):
+        """NaN (a NaN in either half of a word) from the first whole row
+        tile past ``live`` on."""
+        tile = moe_rows.row_tile(rows)
+        first = jnp.maximum(-(-live // tile), 1) * tile // words
+        dead = jnp.arange(out.shape[0]) >= first
+        if not isinstance(first, jax.core.Tracer):
+            poisoned.append(int(jnp.sum(dead)))
+        nan = 0x7FC07FC0 if out.dtype == jnp.uint32 else jnp.nan
+        return jnp.where(dead.reshape((-1,) + (1,) * (out.ndim - 1)),
+                         jnp.full_like(out, nan), out)
+
+    def dispatch_rows(src, index, live, *args, **kw):
+        out = real(src, index, live, *args, **kw)
+        if isinstance(out, tuple):
+            return (poison(out[0], live, index.shape[0], 1),) + out[1:]
+        return poison(out, live, index.shape[0], 1)
+
+    def stage_rows(x, live, **kw):
+        out = real(x, live, **kw)
+        rows = out.shape[0] * moe_rows.packing(x.dtype)
+        return poison(out, live, rows, moe_rows.packing(x.dtype))
+
+    monkeypatch.setattr(moe_rows, kernel, locals()[kernel])
+    dirty = jax.value_and_grad(prog, (0, 1), has_aux=True)(p, x)
+    assert max(poisoned) > 0      # some whole tile past live was poisoned
+    for a, b in zip(jax.tree_util.tree_leaves(clean),
+                    jax.tree_util.tree_leaves(dirty)):
+        assert np.all(np.isfinite(np.asarray(b)))
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))

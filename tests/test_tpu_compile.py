@@ -18,6 +18,7 @@ from jax.sharding import NamedSharding, PartitionSpec, SingleDeviceSharding
 from repro.configs import ARCH_IDS, get_config, get_optimizer_name
 from repro.configs.shapes import train_batch_specs
 from repro.kernels.flash_attention import fits
+from repro.kernels.moe_rows import KERNEL_NAMES as ROW_KERNEL_NAMES
 from repro.kernels.ops import flash_attention, rglru_scan
 from repro.launch.mesh import make_mesh
 from repro.launch.steps import jit_train_step, train_in_shardings
@@ -205,11 +206,35 @@ def mellum_stage():
                        moe=dataclasses.replace(cfg.moe, held=8))
 
 
+MOE_SCOPES = ("router", "dispatch", "experts", "combine")
+# Each row kernel of the MoE layer runs in both scopes: dispatch forward,
+# then in the gradient as combine's transpose, and the other way round.
+ROW_KERNELS = {(kernel, scope) for kernel in ROW_KERNEL_NAMES
+               for scope in ("dispatch", "combine")}
+
+
+def _row_kernel_scopes(text: str):
+    """(kernel, moe sub-scope) of each row kernel call in HLO ``text``."""
+    found = set()
+    for line in text.splitlines():
+        call = re.match(r"\s*(?:ROOT )?%(\w+)[.\d]* = .*" + MOSAIC, line)
+        if call and call.group(1) in ROW_KERNEL_NAMES:
+            path = re.search(r'op_name="([^"]*)"', line).group(1).split("/")
+            # each component without its jvp(...)/transpose(...) wrappers
+            path = [re.sub(r"^(?:[\w.-]+\()+|\)+$", "", c) for c in path]
+            inner = [c for c in path[path.index("moe"):] if c in MOE_SCOPES]
+            found.add((call.group(1), inner[-1] if inner else None))
+    return found
+
+
 @pytest.mark.parametrize("chips,kernel", [(1, "gmm"), (4, "ragged-dot")])
 def test_expert_products_dispatch(topo, chips, kernel):
     """One chip runs the held experts' products as the Pallas grouped
-    matmul (``gmm``, ``tgmm`` in the backward); a mesh of four, which a
-    Mosaic kernel cannot be partitioned over, as ``lax.ragged_dot``."""
+    matmul (``gmm``, ``tgmm`` in the backward) and moves the rows with the
+    row kernels, inside the ``moe/dispatch`` and ``moe/combine`` scopes,
+    with no gather over the whole T * min(k, held) buffer; a mesh of four,
+    which a Mosaic kernel cannot be partitioned over, runs
+    ``lax.ragged_dot`` and the gathers, and no row kernel."""
     cfg = mellum_stage()
     mesh = make_mesh(topo.devices[:chips])
     place = NamedSharding(mesh, PartitionSpec())
@@ -224,21 +249,34 @@ def test_expert_products_dispatch(topo, chips, kernel):
     def loss(p, x):
         with use_mesh(mesh if chips > 1 else None):
             y, aux = moe.apply_moe(p, x, cfg)
-        return jnp.sum(y.astype(jnp.float32)) + aux["aux_loss"]
+        return jnp.sum(jnp.square(y.astype(jnp.float32))) + aux["aux_loss"]
 
-    text = jax.jit(jax.grad(loss)).lower(params, x).compile().as_text()
+    text = jax.jit(jax.grad(loss, (0, 1))).lower(params, x).compile(
+    ).as_text()
     names = set(re.findall(r"%(t?gmm)[.\d]* = ", text))
+    rows = 2 * 512 * min(cfg.moe.top_k, cfg.moe.n_held)
+    buffer_gathers = re.findall(
+        r"= bf16\[%d,%d\][^ ]* gather\(" % (rows, cfg.d_model), text)
     if kernel == "gmm":
         assert names == {"gmm", "tgmm"} and "ragged-dot" not in text
+        assert _row_kernel_scopes(text) == ROW_KERNELS
+        assert not buffer_gathers
     else:
         assert not names and "ragged-dot" in text
+        assert not _row_kernel_scopes(text) and buffer_gathers
+
+
+# Temporaries of that step when the expert rows moved by gathers over the
+# whole buffer: the row kernels may not hold more.
+MELLUM_TEMP_BYTES = 6_201_294_336
 
 
 def test_mellum_train_step_fits_one_chip(topo):
     """The mellum2-12b-4l benchmark step, batch 8 x 4096, f32 params +
-    AdamW, on one chip: it fits HBM, the sliding and full layers take the
-    fused attention kernels, the experts the grouped matmul, and no array
-    holds the S x S scores."""
+    AdamW, on one chip: it fits HBM with no more temporaries than the
+    gathers took, the sliding and full layers take the fused attention
+    kernels, the experts the grouped matmul and the row kernels, and no
+    array holds the S x S scores."""
     cfg = mellum_stage()
     opt = make_optimizer("adamw", lr=3e-4)
     mesh = make_mesh(topo.devices[:1])
@@ -251,8 +289,9 @@ def test_mellum_train_step_fits_one_chip(topo):
     used = (m.argument_size_in_bytes + m.output_size_in_bytes
             - m.alias_size_in_bytes + m.temp_size_in_bytes)
     assert used <= V5E_HBM_BYTES, used / 2**30
+    assert m.temp_size_in_bytes <= MELLUM_TEMP_BYTES, m.temp_size_in_bytes
     text = compiled.as_text()
     for kernel in ("flash_attention_fwd", "flash_attention_dq",
-                   "flash_attention_dkv", "gmm", "tgmm"):
+                   "flash_attention_dkv", "gmm", "tgmm") + ROW_KERNEL_NAMES:
         assert re.search(r"%" + kernel + r"[.\d]* = ", text), kernel
     assert not _score_buffers(text, 8, cfg.n_kv, 4096)
